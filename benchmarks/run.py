@@ -35,6 +35,9 @@ def _git_sha() -> str:
 
 
 def main() -> None:
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (chaos, obs_overhead, paper, persist, query_path,
                             recall, serving, streaming, tiering)
 

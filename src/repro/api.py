@@ -95,9 +95,10 @@ class IndexConfig:
     * ``backend`` — scoring backend for every search on this index
       (``reference | grouped | pallas``; None → the process default, i.e.
       ``REPRO_SCORE_BACKEND`` or pallas).
-    * ``shards`` — >1 serves the mesh-sharded SPMD index over a host-local
-      mesh (pass an explicit ``mesh`` to :func:`open_index` for real
-      topologies).
+    * ``shards`` — >1 serves the mesh-sharded SPMD index over the first
+      ``shards`` devices: chips on an accelerator, forced host devices on
+      the CPU platform (pass an explicit ``mesh`` to :func:`open_index` for
+      other layouts).
     * ``durability`` — optional :class:`DurabilityConfig` block.
     * ``device_budget_mb`` — cap on the PER-DEVICE bytes of raw vector
       rows; setting it serves the hot/cold tiered index (sketches stay
@@ -170,9 +171,10 @@ def _host_mesh(shards: int):
     if n_dev < shards:
         raise RuntimeError(
             f"IndexConfig.shards={shards} but only {n_dev} device(s) are "
-            f"visible; on CPU force host devices BEFORE importing jax, e.g. "
+            f"visible; on an accelerator each shard needs its own chip; on "
+            f"the CPU platform force host devices BEFORE importing jax, e.g. "
             f'os.environ["XLA_FLAGS"] = '
-            f'"--xla_force_host_platform_device_count={shards}", or pass an '
+            f'"--xla_force_host_platform_device_count={shards}"; or pass an '
             f"explicit mesh to open_index")
     return meshlib.make_mesh((1, shards), ("data", "model"))
 

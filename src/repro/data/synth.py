@@ -10,14 +10,19 @@ we reproduce the *statistical shape* of each collection (Table 3 + Figure 6):
   * ψ_d / ψ_q : mean non-zeros per document / query (Table 3)
 
 Everything is deterministic in the seed and generated in NumPy (host data
-pipeline), streamed in padded (idx, val) batches.
+pipeline), streamed in padded (idx, val) batches.  At corpus scale
+(10^5-10^7 documents) :func:`make_corpus_bulk` draws the same distribution
+as one vectorized JAX program per chunk, on whatever device JAX runs on.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Iterator, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 
@@ -115,6 +120,101 @@ def sample_sparse_batch(
         idx[b, :c] = np.sort(coords)
         val[b, :c] = _draw_values(gen, c, spec)
     return idx, val
+
+
+def _draw_values_jax(key, shape, spec: SparseDatasetSpec) -> jax.Array:
+    if spec.value_dist == "gaussian":
+        v = spec.value_param * jax.random.normal(key, shape)
+    elif spec.value_dist == "uniform":
+        v = jax.random.uniform(key, shape, minval=-1.0, maxval=1.0)
+    elif spec.value_dist == "zeta":
+        pmf = np.arange(1, 1025, dtype=np.float64) ** (-spec.value_param)
+        v = jax.random.choice(key, jnp.linspace(-1.0, 1.0, 1024), shape,
+                              p=jnp.asarray(pmf / pmf.sum(), jnp.float32))
+    elif spec.value_dist == "lognormal":
+        v = jnp.exp(spec.value_param * jax.random.normal(key, shape))
+    else:
+        raise ValueError(spec.value_dist)
+    if spec.nonneg:
+        v = jnp.abs(v)
+    return jnp.where(v == 0.0, 1e-6, v).astype(jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5))
+def _bulk_chunk(key, cdf, spec: SparseDatasetSpec, batch: int, psi: int,
+                pad: int):
+    """One chunk of :func:`sample_sparse_bulk`: (idx int32, val f32)."""
+    kc, kd, ko, kv = jax.random.split(key, 4)
+    counts = jnp.clip(jax.random.poisson(kc, psi, (batch,)), 1, pad)
+    draws = 2 * pad
+    coords = jnp.searchsorted(cdf, jax.random.uniform(kd, (batch, draws)),
+                              side="right")
+    coords = jnp.minimum(coords, spec.n - 1).astype(jnp.int32)
+    # 2c draws per vector (unused draws -> the sentinel n), deduplicated.
+    coords = jnp.where(jnp.arange(draws) < 2 * counts[:, None], coords,
+                       spec.n)
+    coords = jnp.sort(coords, axis=1)
+    repeat = jnp.concatenate([jnp.zeros((batch, 1), jnp.bool_),
+                              coords[:, 1:] == coords[:, :-1]], axis=1)
+    coords = jnp.where(repeat, spec.n, coords)
+    # Keep c of the distinct coordinates, chosen uniformly at random.
+    keys = jnp.where(coords < spec.n,
+                     jax.random.uniform(ko, (batch, draws)), 2.0)
+    order = jnp.argsort(keys, axis=1)[:, :pad]
+    coords = jnp.take_along_axis(coords, order, axis=1)
+    coords = jnp.where(jnp.arange(pad) < counts[:, None], coords, spec.n)
+    coords = jnp.sort(coords, axis=1)
+    valid = coords < spec.n
+    val = _draw_values_jax(kv, (batch, pad), spec)
+    return jnp.where(valid, coords, -1), jnp.where(valid, val, 0.0)
+
+
+def sample_sparse_bulk(seed: int, spec: SparseDatasetSpec, batch: int,
+                       psi: int, pad: int, *, chunk: int = 1 << 16
+                       ) -> Tuple[np.ndarray, np.ndarray]:
+    """Vectorized twin of :func:`sample_sparse_batch` for corpus scale.
+
+    Same law per vector — ψ ~ Poisson(psi) clipped to [1, pad]; 2ψ
+    activation draws (Zipf or uniform over the coordinates), deduplicated,
+    of which ψ are kept at random; values from the spec — drawn with
+    ``jax.random`` in chunks of ``chunk`` vectors, so the numbers differ
+    from :func:`sample_sparse_batch` for the same seed.  (For uniform
+    activation the draws are with replacement, so a vector may keep fewer
+    than ψ coordinates when duplicates leave fewer than ψ distinct ones.)
+    Chunks are spread over the local devices, one wave of chunks in flight
+    at a time; the numbers do not depend on the device count.  Returns host
+    arrays (idx int32[batch, pad], val f32[batch, pad]); pad idx = -1.
+    """
+    devices = jax.local_devices()
+    cdf = np.cumsum(_coord_weights(spec)).astype(np.float32)
+    cdfs = [jax.device_put(cdf, d) for d in devices]
+    key = jax.random.key(seed)
+    idx = np.empty((batch, pad), np.int32)
+    val = np.empty((batch, pad), np.float32)
+    starts = list(range(0, batch, chunk))
+    for w in range(0, len(starts), len(devices)):
+        wave = []
+        for j, lo in enumerate(starts[w:w + len(devices)]):
+            b = min(chunk, batch - lo)
+            k = jax.device_put(jax.random.fold_in(key, w + j), devices[j])
+            wave.append((lo, b, _bulk_chunk(k, cdfs[j], spec, b, psi, pad)))
+        for lo, b, (ci, cv) in wave:
+            idx[lo:lo + b] = np.asarray(ci)
+            val[lo:lo + b] = np.asarray(cv)
+    return idx, val
+
+
+def make_corpus_bulk(seed: int, spec: SparseDatasetSpec, n_docs: int,
+                     pad: int):
+    """:func:`make_corpus` through the vectorized :func:`sample_sparse_bulk`."""
+    return sample_sparse_bulk(seed, spec, n_docs, spec.psi_doc, pad)
+
+
+def make_queries_bulk(seed: int, spec: SparseDatasetSpec, n_queries: int,
+                      pad: int):
+    """:func:`make_queries` through :func:`sample_sparse_bulk`."""
+    return sample_sparse_bulk(seed ^ 0x5EED, spec, n_queries,
+                              spec.psi_query, pad)
 
 
 def make_corpus(seed: int, spec: SparseDatasetSpec, n_docs: int,

@@ -5,5 +5,8 @@
   embed_bag      — EmbeddingBag gather-reduce (recsys substrate)
 
 Each kernel has a pure-jnp oracle in ref.py and a jit'd wrapper in ops.py.
-Validated in interpret mode on CPU; compiled pl.pallas_call on TPU.
+Validated in interpret mode on CPU.  On a TPU the serving path runs
+sinnamon_score compiled (its compile for v5e is a test:
+tests/test_tpu_compile.py); csr_score and embed_bag are reached only by
+their interpret-mode tests.
 """

@@ -40,7 +40,7 @@ def csr_score(
     values: jax.Array,           # [C, P]
     *,
     tile_c: int = DEFAULT_TILE_C,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Exact scores f32[C] for one query."""
     C, P = indices.shape

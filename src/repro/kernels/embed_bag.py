@@ -40,7 +40,7 @@ def embed_bag(
     indices: jax.Array,      # int32[B, F]  (pad = -1)
     weights: jax.Array,      # f32[B, F]    (0 at padded slots)
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Weighted-sum bags f32[B, D]."""
     B, F = indices.shape

@@ -2,21 +2,22 @@
 dispatch point.
 
 Handles operand preparation (query sorting/budgeting, membership-row
-gathering, tile padding) and implementation selection: compiled Pallas on
-TPU; elsewhere the dense kernels run in interpret mode (the mandated
-validation path) while the fused serving path runs its XLA twin — the same
-tile program without the per-grid-step interpreter overhead (interpret-mode
-execution of the fused kernel remains available via ``use_kernel=True`` and
-is what the equivalence tests exercise).
+gathering, tile padding) and implementation selection: the compiled Pallas
+kernel on a TPU, never the interpreter or the twin there; elsewhere the
+dense kernel wrappers run in interpret mode (the validation path) while the
+fused serving path runs its XLA twin — the kernel's per-slot program without
+the per-grid-step interpreter overhead (interpret-mode execution of the fused
+kernel remains available via ``use_kernel=True`` and is what the
+equivalence tests exercise).
 
 Scoring-backend dispatch
 ------------------------
 Every query hot path (``engine.search``/``search_batch``, both serving
 layers, the launcher) routes candidate generation through ONE selector:
 
-* ``pallas``    — the fused tiled kernel (``sinnamon_score_topk`` + log-tree
-  merge): never materializes the ``[B, C]`` score matrix.  The production
-  default.
+* ``pallas``    — the tiled kernel: it writes the gated ``[B, C]`` score
+  matrix once and XLA takes one top-k over it (on the CPU the kernel's XLA
+  twin writes the same scores).  The production default.
 * ``grouped``   — ``engine.score_grouped`` (one fused [L, C] pass) + dense
   ``lax.top_k``.
 * ``reference`` — paper-faithful coordinate-at-a-time ``engine.score`` +
@@ -36,14 +37,12 @@ repro.kernels.sinnamon_score).
 
 from __future__ import annotations
 
-import functools
 import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import csr_score as _csr
 from repro.kernels import embed_bag as _bag
 from repro.kernels import sinnamon_score as _sinn
 
@@ -109,7 +108,7 @@ def sinnamon_score_batch(state, qv, rows, qbits, *, tile_c=None,
                          interpret=None):
     """Kernel-backed Algorithm 6 over a query batch. f32[B, C]."""
     C = state.u.shape[1]
-    tile_c = tile_c or min(_sinn.DEFAULT_TILE_C, C)
+    tile_c = tile_c or _default_tile(C, _sinn.DEFAULT_TILE_C)
     interpret = _interpret() if interpret is None else interpret
     u = pad_axis(state.u, 1, tile_c)
     l = None if state.l is None else pad_axis(state.l, 1, tile_c)
@@ -119,110 +118,81 @@ def sinnamon_score_batch(state, qv, rows, qbits, *, tile_c=None,
     return out[:, :C]
 
 
-def prepare_fused_operands(state, q_idx, q_val, budget=None, spec=None):
-    """Query + state -> (qv, pos, rows, qbits, skmat, one_sided) for the
-    fused kernel / XLA twin.
+def _default_tile(C: int, full: int) -> int:
+    """``full``, or all of a small C rounded up to 256 slots — for the
+    kernel, every block is then (8, 128)-aligned or spans the whole padded
+    slot axis."""
+    return min(full, ((C + 255) // 256) * 256)
 
-    On top of :func:`prepare_query_operands`: splits coordinate signs, stacks
-    ``[U; L]`` into one gather matrix and pre-offsets negative coordinates'
-    sketch rows by +m, so the fused path reads each sketch cell ONE-SIDED —
-    half the decode work of the reference scorer.
+
+def prepare_fused_operands(state, q_idx, q_val, budget=None, spec=None):
+    """Query + state -> (qv, rows, qbits, skmat, one_sided) for the fused
+    kernel / XLA twin.
+
+    On top of :func:`prepare_query_operands`: stacks ``[U; L]`` into one
+    gather matrix and pre-offsets non-positive coordinates' sketch rows by
+    +m, so the fused path reads each sketch cell ONE-SIDED — half the decode
+    work of the reference scorer.
     """
     qv, rows, qbits = prepare_query_operands(state, q_idx, q_val, budget,
                                              spec=spec)
-    pos = qv > 0
-    if state.l is None:
-        return qv, pos, rows, qbits, state.u, False
-    m = state.u.shape[0]
-    skmat = jnp.concatenate([state.u, state.l], axis=0)       # [2m, C]
-    rows = jnp.where(pos[..., None], rows, rows + m)
-    return qv, pos, rows, qbits, skmat, True
+    rows, skmat, one_sided = _sinn.one_sided_operands(qv, rows, state.u,
+                                                      state.l)
+    return qv, rows, qbits, skmat, one_sided
 
 
-def sinnamon_tile_topk(state, spec, q_idx, q_val, kprime, *, budget=None,
-                       ok=None, tile_c=None, query_block=2,
-                       use_kernel=None, interpret=None):
-    """Sketch-scan stage of the fused path: per-tile candidates, pre-merge.
+def sinnamon_candidate_scores(state, spec, q_idx, q_val, *, budget=None,
+                              ok=None, tile_c=None, use_kernel=None,
+                              interpret=None):
+    """Sketch-scan stage of the fused path: gated upper-bound scores
+    f32[B, C] (``-inf`` where ``ok`` is False).
 
-    Prepares sign-split operands, pads the slot axis to a tile multiple
-    (padded slots are gated to -inf so they can never become candidates —
-    works at any post-``grow()`` capacity) and runs the fused
-    score→top-kp tile program.  Returns ``(vals f32[B, T, kp],
-    slots int32[B, T, kp])`` still per-tile; feed through
-    :func:`repro.kernels.sinnamon_score.merge_tile_topk` (or call
-    :func:`sinnamon_topk_batch` which does both).  Split out so the staged
-    query tracer can time sketch scan and top-k merge separately.
+    Prepares one-sided operands and runs the tile program; the kernel's
+    slot axis is padded to a tile multiple (padded slots are gated to -inf
+    and sliced off — works at any post-``grow()`` capacity).  Split from the
+    top-k (:func:`sinnamon_topk_batch` does both) so the staged query tracer
+    can time the sketch scan and the top-k separately.
+
+    On a TPU this is always the compiled kernel (``use_kernel`` and
+    ``interpret`` default to it); elsewhere the XLA twin
+    :func:`repro.kernels.sinnamon_score.scores_xla`, unless
+    ``use_kernel=True`` asks for the interpreted kernel.
+    """
+    C = state.u.shape[1]
+    use_kernel = on_tpu() if use_kernel is None else use_kernel
+    qv, rows, qbits, skmat, one_sided = prepare_fused_operands(
+        state, q_idx, q_val, budget, spec=spec)
+    keep = jnp.ones((C,), jnp.bool_) if ok is None else ok
+    gate = jnp.where(keep, 0.0, -jnp.inf).astype(jnp.float32)[None]
+    if not use_kernel:
+        return _sinn.scores_xla(qv, rows, qbits, gate, skmat,
+                                one_sided=one_sided)
+    tile_c = tile_c or _default_tile(C, _sinn.DEFAULT_TILE_C)
+    interpret = _interpret() if interpret is None else interpret
+    s = _sinn.tile_scores(
+        qv, rows, pad_axis(qbits, -1, tile_c // 32),
+        pad_axis(gate, -1, tile_c, fill=-jnp.inf), pad_axis(skmat, 1, tile_c),
+        tile_c=tile_c, one_sided=one_sided, interpret=interpret)
+    return s[:, :C]
+
+
+def sinnamon_topk_batch(state, spec, q_idx, q_val, kprime, *, budget=None,
+                        ok=None, tile_c=None, use_kernel=None,
+                        interpret=None):
+    """Fused candidate generation: (vals f32[B, kprime], slots int32[B, kprime]).
+
+    The full search front half: :func:`sinnamon_candidate_scores`, then one
+    ``lax.top_k`` over all slots.  ``ok``: optional bool[C] keep-mask
+    (active & filter); the result is in (upper-bound desc, slot asc) order —
+    the order of every other backend.
     """
     C = state.u.shape[1]
     if kprime > C:
         raise ValueError(f"kprime={kprime} > capacity {C}")
-    use_kernel = on_tpu() if use_kernel is None else use_kernel
-    if tile_c is None:
-        full = _sinn.DEFAULT_TILE_C if use_kernel else _sinn.DEFAULT_TILE_C_XLA
-        tile_c = min(full, ((C + 255) // 256) * 256)   # whole (padded) C if small
-    qv, pos, rows, qbits, skmat, one_sided = prepare_fused_operands(
-        state, q_idx, q_val, budget, spec=spec)
-    skmat = pad_axis(skmat, 1, tile_c)
-    qbits_p = pad_axis(qbits, -1, tile_c // 32)
-    keep = jnp.ones((C,), jnp.bool_) if ok is None else ok
-    gate = jnp.where(keep, 0.0, -jnp.inf).astype(jnp.float32)[None]
-    gate = pad_axis(gate, -1, tile_c, fill=-jnp.inf)
-    kp = min(kprime, tile_c)
-    if use_kernel:
-        interpret = _interpret() if interpret is None else interpret
-        return _sinn.sinnamon_score_topk(
-            qv, pos, rows, qbits_p, gate, skmat, kp=kp, tile_c=tile_c,
-            one_sided=one_sided, interpret=interpret)
-    return _sinn.fused_topk_xla(
-        qv, pos, rows, qbits_p, gate, skmat, kp=kp, tile_c=tile_c,
-        one_sided=one_sided, query_block=query_block)
-
-
-def sinnamon_topk_batch(state, spec, q_idx, q_val, kprime, *, budget=None,
-                        ok=None, tile_c=None, query_block=2,
-                        use_kernel=None, interpret=None):
-    """Fused candidate generation: (vals f32[B, kprime], slots int32[B, kprime]).
-
-    The full search front half in one pipeline: the per-tile scan
-    (:func:`sinnamon_tile_topk`) followed by the log-tree merge.
-
-    Implementation selection: the Pallas kernel where it compiles (TPU), the
-    XLA twin of the same tile program elsewhere (CPU serving); pass
-    ``use_kernel=True`` to force the kernel (interpret-mode validation).
-
-    ``ok``: optional bool[C] keep-mask (active & filter); ordering of the
-    result is (upper-bound desc, slot asc) — lax.top_k order over the gated
-    fused scores.
-    """
-    vals, slots = sinnamon_tile_topk(
-        state, spec, q_idx, q_val, kprime, budget=budget, ok=ok,
-        tile_c=tile_c, query_block=query_block, use_kernel=use_kernel,
-        interpret=interpret)
-    return _sinn.merge_tile_topk(vals, slots, kprime)
-
-
-def make_engine_score_fn(tile_c=None, interpret=None):
-    """A drop-in ``score_fn`` for `repro.core.engine.search` (single query)."""
-
-    def score_fn(state, spec, q_idx, q_val, budget=None):
-        qv, rows, qbits = prepare_query_operands(
-            state, q_idx[None], q_val[None], budget, spec=spec)
-        return sinnamon_score_batch(state, qv, rows, qbits, tile_c=tile_c,
-                                    interpret=interpret)[0]
-
-    return score_fn
-
-
-def exact_scores_all(store, q_dense, *, tile_c=None, interpret=None):
-    """Kernel-backed exact document-ordered scan (TPU-native LinScan)."""
-    C = store.indices.shape[0]
-    tile_c = tile_c or min(_csr.DEFAULT_TILE_C, C)
-    interpret = _interpret() if interpret is None else interpret
-    idx = pad_axis(store.indices, 0, tile_c, fill=-1)
-    val = pad_axis(store.values, 0, tile_c)
-    qd = pad_axis(q_dense, 0, 128)
-    return _csr.csr_score(qd, idx, val, tile_c=tile_c,
-                          interpret=interpret)[:C]
+    scores = sinnamon_candidate_scores(
+        state, spec, q_idx, q_val, budget=budget, ok=ok, tile_c=tile_c,
+        use_kernel=use_kernel, interpret=interpret)
+    return _sinn.topk_candidates(scores, kprime)
 
 
 def embed_bag(table, indices, weights=None, *, mode="sum", interpret=None):

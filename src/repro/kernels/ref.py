@@ -60,8 +60,8 @@ def sinnamon_topk_ref(
     are elementwise identical), sums all coordinate contributions in one
     dense [B, L, C] pass, then takes a global top-k.  Returns
     (vals f32[B, kprime], slots int32[B, kprime]) in lax.top_k order
-    (score desc, ties by slot asc) — the contract sinnamon_score_topk +
-    merge_tile_topk (and the XLA twin) must reproduce bit-for-bit.
+    (score desc, ties by slot asc) — the contract sinnamon_score_topk (and
+    the XLA twin under the same top-k) must reproduce bit-for-bit.
     """
     B, Lq = qv.shape
     C = u.shape[1]

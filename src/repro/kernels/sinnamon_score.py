@@ -4,55 +4,53 @@ This is the paper's hot spot: for each query coordinate, read h sketch rows,
 take the elementwise min (max for the lower sketch), mask by the bit-packed
 inverted index, scale by q[j] and accumulate.
 
-TPU schedule (the *beyond-paper* tile-resident formulation — see DESIGN.md §2):
-the grid walks document tiles of size ``TC`` along the slot axis; the full
-sketch block ``[m, TC]`` is resident in VMEM while **all** budgeted query
-coordinates stream over it, so each sketch tile is fetched from HBM exactly
-once per query (the faithful coordinate-at-a-time order would fetch ``h``
-rows per coordinate — same arithmetic, ψ_q·h/m× the HBM traffic when
-ψ_q·h > m).  Membership words are pre-gathered per query coordinate
-(``uint32[L, TC/32]`` per tile) and unpacked lane-wise in-kernel.
+TPU schedule (the tile-resident formulation): the grid is ``(B, T)`` over
+queries and document tiles of ``TC`` slots.  The sketch block of one tile is
+resident in VMEM while every budgeted query coordinate streams over it, so
+each sketch tile is fetched from HBM once per query (the faithful
+coordinate-at-a-time order would fetch ``h`` rows per coordinate).
 
-Block shapes: sketches ``(m, TC)``, membership ``(1, L, TW)``, scores
-``(1, TC)`` with ``TC`` a multiple of 128 lanes (f32 tile 8×128; the m axis is
-the sublane axis).  VMEM footprint ≈ 2·m·TC·2B + L·TC/8 + TC·4B — e.g.
-m=128, TC=2048, L=64: 1.1 MiB, comfortably inside the ~16 MiB VMEM budget.
+Layout (what Mosaic lowers):
 
-Two entry points share the schedule:
+* a tile of ``TC = S * 128`` slots is an ``[S, 128]`` block, slot
+  ``128 * i + j`` at sublane ``i``, lane ``j``.  The sketch is viewed as
+  ``[R, C / 128, 128]``, so the sketch row of a coordinate is a read at a
+  dynamic index of the leading (untiled) axis of the ``(R, S, 128)`` block;
+* the sketch-row ids and the query values are scalar-prefetched into SMEM;
+* membership words are re-packed outside the kernel into lane-major bit
+  planes (:func:`lane_major_words`): lane ``j`` of a tile's plane holds, in
+  bit ``i``, the bit of slot ``128 * i + j``.  The kernel unpacks a plane
+  with one shift by the sublane iota, so ``S <= 32`` and the default tile
+  ``TC = 4096`` fills the 32 bits of every plane word.
 
-* :func:`sinnamon_score` — the original dense variant, returns ``f32[B, C]``.
-* :func:`sinnamon_score_topk` — the FUSED serving variant: each grid tile
-  reduces its ``TC`` upper-bound scores to a ``kp``-candidate buffer
-  (scores + global slot ids) **in-kernel**, so the full ``[B, C]`` score
-  matrix never exists.  Tile buffers are then combined by
-  :func:`merge_tile_topk`, a log-tree merge that sorts on the explicit key
-  (score desc, slot asc) — the exact tie order of ``lax.top_k`` over a
-  dense score vector.
+VMEM footprint per grid step: two ``(R, S, 128)`` sketch buffers
+(``2 * R * TC * cell bytes``: 1 MiB at R=64 in bf16), the ``[L, 128]``
+plane block, and the ``[S, 128]`` gate and score blocks.
 
-The fused variant also changes the decode schedule (the perf tentpole):
+Entry points:
 
-* ONE-SIDED gathers: Algorithm 6 needs ``u``-cells only where ``q[j] > 0``
-  and ``l``-cells only where ``q[j] < 0``, so the wrapper concatenates
-  ``[U; L]`` into one ``[2m, C]`` matrix and pre-offsets each coordinate's
-  sketch rows by the query sign — HALF the gather + reduce work of the
-  reference decode, which always reads both sides.
-* VECTORIZED coordinates: all budgeted coordinates form one ``[L, TC]``
-  contribution block reduced in a single pass, instead of ψ_q sequential
-  read-modify-write sweeps of the accumulator.  (Summation association
-  differs from the sequential reference in the last ulp; candidate slots —
-  and therefore the exact-reranked ids — are asserted identical in tests.)
+* :func:`tile_scores` — the kernel: gated upper-bound scores ``f32[B, C]``.
+* :func:`sinnamon_score` — dense scores from separate ``u``/``l`` sketches.
+* :func:`sinnamon_score_topk` — the serving program: kernel scores, then
+  one ``lax.top_k`` over all slots in XLA (:func:`topk_candidates`; Mosaic
+  has no top-k lowering), so the gated ``[B, C]`` score matrix is written
+  to HBM once per batch (64 MiB at B=16, C=2^20).
 
-Quantized sketch cells (``EngineSpec.dtype`` = f32 | bf16 | f8) are decoded
-*inside* the tile loop: every entry point gathers the narrow cells and
-upcasts with ``.astype(f32)`` after the gather, so the HBM-resident sketch —
-and the VMEM block the grid streams — stays at the narrow storage width and
-the f32 math is confined to the tile registers.
+The operands are ONE-SIDED: Algorithm 6 needs ``u``-cells only where
+``q[j] > 0`` and ``l``-cells only where ``q[j] < 0``, so the caller stacks
+``[U; L]`` into one ``[2m, C]`` matrix and pre-offsets each coordinate's
+sketch rows by ``+m`` for non-positive coordinates — half the gather and
+reduce work of the reference decode, which reads both sides.
 
-:func:`fused_topk_xla` is the same tile program expressed as a lax.scan for
-backends without a compiled Pallas lowering (CPU serving): identical math,
-identical tile shapes, no per-grid-step interpreter overhead.  Interpret-mode
-``pallas_call`` remains the kernel-validation path (tests assert kernel ==
-twin == dense oracle on the same operands).
+Quantized sketch cells (``EngineSpec.dtype`` = f32 | bf16 | f8) are upcast
+to f32 after the row read, so the HBM-resident sketch and the VMEM block
+stay at the narrow storage width.
+
+:func:`scores_xla` is the kernel's per-slot program in plain XLA, for
+backends without a compiled Pallas lowering (CPU serving); it writes the
+same scores and feeds the same top-k.  Interpret-mode ``pallas_call`` is the
+kernel-validation path: tests assert kernel == twin == dense oracle on the
+same operands.
 """
 
 from __future__ import annotations
@@ -63,112 +61,132 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_TILE_C = 2048
-# CPU/XLA-twin tile: big tiles amortize per-tile top_k and scan overhead on
-# CPU (no VMEM ceiling); the TPU kernel keeps the VMEM-sized DEFAULT_TILE_C.
-DEFAULT_TILE_C_XLA = 8192
-_SLOT_SENTINEL = jnp.iinfo(jnp.int32).max
+DEFAULT_TILE_C = 4096          # [32, 128] slots: one plane word per lane
+LANES = 128
+_WORD = 32
 
 
-def _accumulate(qv_ref, rows_ref, qbits_ref, u_ref, l_ref, *,
-                budget: int, h: int, tile_c: int):
-    """Shared Algorithm 6 inner loop: upper-bound scores f32[TC] of one tile.
+def _check_tile(C: int, tile_c: int) -> None:
+    if tile_c % LANES or not LANES <= tile_c <= _WORD * LANES:
+        raise ValueError(f"tile_c={tile_c} must be a multiple of {LANES} "
+                         f"in [{LANES}, {_WORD * LANES}]")
+    if C % tile_c != 0:
+        raise ValueError(f"C={C} must be a multiple of tile_c={tile_c}")
 
-    Accumulates coordinate contributions SEQUENTIALLY (fori_loop) in the
-    sorted-|q[j]| order, i.e. the exact same f32 add sequence per slot as the
-    reference ``engine.score`` loop — the scores (and therefore any top-k cut
-    over them) come out bit-identical to the reference backend.
+
+def lane_major_words(qbits: jax.Array, tile_c: int) -> jax.Array:
+    """Slot-major membership words -> the kernel's lane-major bit planes.
+
+    ``qbits`` uint32[B, L, C/32] holds slot ``32 * w + r`` in bit ``r`` of
+    word ``w``.  Returns uint32[B, T, L, 128] with T = C / tile_c, where bit
+    ``i`` of lane ``j`` in tile ``c`` is the bit of slot
+    ``c * tile_c + 128 * i + j``.
     """
-    U = u_ref[...].astype(jnp.float32)                    # [m, TC]
-    L = None if l_ref is None else l_ref[...].astype(jnp.float32)
-    qv = qv_ref[0]                                        # [Lq]
-    rows = rows_ref[0]                                    # [Lq, h]
-    words = qbits_ref[0]                                  # [Lq, TW]
-    shifts = jnp.arange(32, dtype=jnp.uint32)
+    B, L, W = qbits.shape
+    S = tile_c // LANES
+    T = W * _WORD // tile_c
+    # Word 4i + q of a tile covers slots 128i + 32q + r, i.e. lane 32q + r.
+    w = qbits.reshape(B, L, T, S, LANES // _WORD)
+    bits = (w[..., None] >> jnp.arange(_WORD, dtype=jnp.uint32)) & 1
+    shift = jnp.arange(S, dtype=jnp.uint32)[:, None, None]
+    planes = jnp.sum(bits << shift, axis=3, dtype=jnp.uint32)   # [B,L,T,4,32]
+    return jnp.swapaxes(planes.reshape(B, L, T, LANES), 1, 2)
 
-    def body(t, acc):
-        r = rows[t]
-        ub = jax.lax.dynamic_index_in_dim(U, r[0], 0, keepdims=False)
+
+def _score_kernel(rows_ref, qv_ref, words_ref, gate_ref, sk_ref, out_ref, *,
+                  n_coords: int, h: int, one_sided: bool):
+    """One (query, tile) grid step: gated upper-bound scores f32[S, 128].
+
+    rows_ref  SMEM int32[B * L * h]  sketch rows (pre-offset when one_sided)
+    qv_ref    SMEM f32[B * L]        query values
+    words_ref VMEM int32[1, 1, L, 128]   lane-major bit planes of this tile
+    gate_ref  VMEM f32[S, 128]       0 keep / -inf excluded
+    sk_ref    VMEM [R, S, 128]       [U; L] rows of this tile
+    """
+    b = pl.program_id(0)
+    S = gate_ref.shape[0]
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (S, LANES), 0)
+
+    def coord(t, acc):
+        base = b * n_coords + t
+        v = qv_ref[base]
+        pos = v > 0
+        x = sk_ref[rows_ref[base * h]].astype(jnp.float32)
         for o in range(1, h):
-            ub = jnp.minimum(
-                ub, jax.lax.dynamic_index_in_dim(U, r[o], 0, keepdims=False))
-        if L is None:
-            lb = jnp.zeros_like(ub)
-        else:
-            lb = jax.lax.dynamic_index_in_dim(L, r[0], 0, keepdims=False)
-            for o in range(1, h):
-                lb = jnp.maximum(
-                    lb, jax.lax.dynamic_index_in_dim(L, r[o], 0, keepdims=False))
-        v = qv[t]
-        contrib = jnp.where(v > 0, v * ub, v * lb)
-        w = words[t]                                      # [TW] uint32
-        mask = ((w[:, None] >> shifts) & 1).reshape(tile_c) != 0
-        return acc + jnp.where(mask, contrib, 0.0)
+            y = sk_ref[rows_ref[base * h + o]].astype(jnp.float32)
+            if one_sided:
+                # positive coords decode U (least upper bound -> min);
+                # negative coords decode L (greatest lower bound -> max).
+                x = jnp.where(pos, jnp.minimum(x, y), jnp.maximum(x, y))
+            else:
+                x = jnp.minimum(x, y)
+        if not one_sided:
+            # positive-only engine: l == 0 exactly, so q<0 contributes q*0.
+            x = jnp.where(pos, x, 0.0)
+        plane = words_ref[0, 0, pl.ds(t, 1), :]           # [1, 128]
+        member = jax.lax.shift_right_logical(plane, shifts) & 1
+        return acc + jnp.where(member != 0, v * x, 0.0)
 
-    return jax.lax.fori_loop(0, budget, body,
-                             jnp.zeros((tile_c,), jnp.float32))
-
-
-def _kernel(qv_ref, rows_ref, qbits_ref, u_ref, l_ref, out_ref, *,
-            budget: int, h: int, tile_c: int):
-    out_ref[0, :] = _accumulate(qv_ref, rows_ref, qbits_ref, u_ref, l_ref,
-                                budget=budget, h=h, tile_c=tile_c)
+    acc = jax.lax.fori_loop(0, n_coords, coord,
+                            jnp.zeros((S, LANES), jnp.float32))
+    out_ref[0] = jnp.where(gate_ref[...] == 0.0, acc, -jnp.inf)
 
 
-def _fused_tile_scores(qv, pos, rows, words, gate, skmat, *, h: int,
-                       one_sided: bool, tile_c: int):
-    """Gated upper-bound scores of one tile block — the SHARED fused math.
+@functools.partial(jax.jit, static_argnames=("tile_c", "one_sided",
+                                             "interpret"))
+def tile_scores(
+    qv: jax.Array,               # f32[B, L]
+    rows: jax.Array,             # int32[B, L, h]  (pre-offset when one_sided)
+    qbits: jax.Array,            # uint32[B, L, W]  (W = C/32)
+    gate: jax.Array,             # f32[1, C]: 0 keep / -inf excluded (or pad)
+    skmat: jax.Array,            # [R, C]  [U; L] stacked (R = 2m, or m)
+    *,
+    tile_c: int,
+    one_sided: bool,
+    interpret: bool,
+) -> jax.Array:
+    """Gated upper-bound scores f32[B, C] from the Pallas kernel.
+    Grid = (B, C / tile_c)."""
+    B, Lq = qv.shape
+    h = rows.shape[-1]
+    R, C = skmat.shape
+    _check_tile(C, tile_c)
+    S = tile_c // LANES
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, C // tile_c),
+        in_specs=[
+            pl.BlockSpec((1, 1, Lq, LANES), lambda b, c, *_: (b, c, 0, 0)),
+            pl.BlockSpec((S, LANES), lambda b, c, *_: (c, 0)),
+            pl.BlockSpec((R, S, LANES), lambda b, c, *_: (0, c, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, S, LANES), lambda b, c, *_: (b, c, 0)),
+    )
+    kern = functools.partial(_score_kernel, n_coords=Lq, h=h,
+                             one_sided=one_sided)
+    out = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, C // LANES, LANES), jnp.float32),
+        interpret=interpret,
+    )(rows.reshape(-1), qv.astype(jnp.float32).reshape(-1),
+      jax.lax.bitcast_convert_type(lane_major_words(qbits, tile_c),
+                                   jnp.int32),
+      gate.reshape(C // LANES, LANES),
+      skmat.reshape(R, C // LANES, LANES))
+    return out.reshape(B, C)
 
-    Both the Pallas kernel body and the XLA twin call exactly this function
-    on identically-shaped operands, so the two lower to the same per-slot
-    float program (tests assert bitwise equality).
 
-    qv/pos:  f32/bool[..., L]    query values and their signs
-    rows:    int32[..., L, h]    sketch rows, PRE-OFFSET by +m for negative
-                                 coordinates when one_sided (see the wrapper)
-    words:   uint32[..., L, TW]  membership words of this tile
-    gate:    f32[TC]             0 keep / -inf excluded
-    skmat:   f32-castable[R, TC] [U; L] rows of this tile (R = 2m, or m when
-                                 the engine runs positive-only)
-    """
-    sk = skmat.astype(jnp.float32)
-    x = sk[rows[..., 0]]                                   # [..., L, TC]
-    for o in range(1, h):
-        y = sk[rows[..., o]]
-        if one_sided:
-            # positive coords decode U (least upper bound -> min); negative
-            # coords decode L (greatest lower bound -> max).
-            x = jnp.where(pos[..., None], jnp.minimum(x, y),
-                          jnp.maximum(x, y))
-        else:
-            x = jnp.minimum(x, y)
-    if not one_sided:
-        # positive-only engine: l == 0 exactly, so q<0 contributes q*0.
-        x = jnp.where(pos[..., None], x, 0.0)
-    contrib = qv[..., None] * x
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    mask = ((words[..., :, None] >> shifts) & 1).reshape(
-        *words.shape[:-1], tile_c) != 0
-    s = jnp.sum(jnp.where(mask, contrib, 0.0), axis=-2)    # [..., TC]
-    return jnp.where(gate == 0.0, s, -jnp.inf)
-
-
-def _topk_kernel(qv_ref, pos_ref, rows_ref, qbits_ref, gate_ref, sk_ref,
-                 val_ref, slot_ref, *, h: int, tile_c: int, kp: int,
-                 one_sided: bool):
-    """Fused tile: score, gate (active/filter/pad -> -inf), reduce to top-kp.
-
-    In-tile selection is ``lax.top_k``, whose tie order (lower index first)
-    is (score desc, slot asc) — the same key the tree merge sorts on.
-    """
-    s = _fused_tile_scores(qv_ref[0], pos_ref[0], rows_ref[0], qbits_ref[0],
-                           gate_ref[0], sk_ref[...], h=h,
-                           one_sided=one_sided, tile_c=tile_c)
-    v, i = jax.lax.top_k(s, kp)
-    base = pl.program_id(1) * tile_c
-    val_ref[0, 0, :] = v
-    slot_ref[0, 0, :] = (base + i).astype(jnp.int32)
+def one_sided_operands(qv: jax.Array, rows: jax.Array, u: jax.Array,
+                       l: Optional[jax.Array]) -> tuple:
+    """(rows, skmat, one_sided) for the kernel from separate sketches:
+    stacks ``[U; L]`` and offsets non-positive coordinates' rows by +m."""
+    if l is None:
+        return rows, u, False
+    rows = jnp.where((qv > 0)[..., None], rows, rows + u.shape[0])
+    return rows, jnp.concatenate([u, l], axis=0), True
 
 
 @functools.partial(jax.jit, static_argnames=("tile_c", "interpret"))
@@ -180,49 +198,26 @@ def sinnamon_score(
     l: Optional[jax.Array] = None,
     *,
     tile_c: int = DEFAULT_TILE_C,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
-    """Upper-bound scores f32[B, C].  Grid = (B, C / tile_c)."""
-    B, Lq = qv.shape
-    h = rows.shape[-1]
-    m, C = u.shape
-    if C % tile_c != 0:
-        raise ValueError(f"C={C} must be a multiple of tile_c={tile_c}")
-    tw = tile_c // 32
-    grid = (B, C // tile_c)
+    """Upper-bound scores f32[B, C] (ungated) through :func:`tile_scores`."""
+    rows, skmat, one_sided = one_sided_operands(qv, rows, u, l)
+    gate = jnp.zeros((1, skmat.shape[1]), jnp.float32)
+    return tile_scores(qv, rows, qbits, gate, skmat, tile_c=tile_c,
+                       one_sided=one_sided, interpret=interpret)
 
-    in_specs = [
-        pl.BlockSpec((1, Lq), lambda b, c: (b, 0)),            # qv
-        pl.BlockSpec((1, Lq, h), lambda b, c: (b, 0, 0)),      # rows
-        pl.BlockSpec((1, Lq, tw), lambda b, c: (b, 0, c)),     # qbits
-        pl.BlockSpec((m, tile_c), lambda b, c: (0, c)),        # u
-    ]
-    args = [qv, rows, qbits, u]
-    if l is not None:
-        in_specs.append(pl.BlockSpec((m, tile_c), lambda b, c: (0, c)))
-        args.append(l)
-        kern = functools.partial(_kernel, budget=Lq, h=h, tile_c=tile_c)
-    else:
-        kern = functools.partial(
-            lambda qv_ref, rows_ref, qbits_ref, u_ref, out_ref, **kw:
-            _kernel(qv_ref, rows_ref, qbits_ref, u_ref, None, out_ref, **kw),
-            budget=Lq, h=h, tile_c=tile_c)
 
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, tile_c), lambda b, c: (b, c)),
-        out_shape=jax.ShapeDtypeStruct((B, C), jnp.float32),
-        interpret=interpret,
-    )(*args)
+def topk_candidates(scores: jax.Array, kp: int) -> tuple:
+    """(vals f32[B, kp], slots int32[B, kp]) of gated scores, in
+    ``lax.top_k`` order: score desc, ties by slot asc."""
+    vals, slots = jax.lax.top_k(scores, kp)
+    return vals, slots.astype(jnp.int32)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("kp", "tile_c", "one_sided", "interpret"))
 def sinnamon_score_topk(
     qv: jax.Array,               # f32[B, L]
-    pos: jax.Array,              # bool[B, L]   q[j] > 0
     rows: jax.Array,             # int32[B, L, h]  (pre-offset when one_sided)
     qbits: jax.Array,            # uint32[B, L, W]  (W = C/32)
     gate: jax.Array,             # f32[1, C]: 0 keep / -inf excluded (or pad)
@@ -231,148 +226,61 @@ def sinnamon_score_topk(
     kp: int,
     tile_c: int = DEFAULT_TILE_C,
     one_sided: bool = True,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple:
-    """Fused scoring + per-tile top-kp.  Returns (vals f32[B, T, kp],
-    slots int32[B, T, kp]) with T = C / tile_c; feed to merge_tile_topk.
+    """The kernel's serving program: :func:`tile_scores`, then
+    :func:`topk_candidates` over all slots.  Returns (vals f32[B, kp],
+    slots int32[B, kp]).
 
-    Operand preparation (sign split, row offsetting, [U; L] stacking, tile
-    padding) lives in repro.kernels.ops.sinnamon_topk_batch.
+    Operand preparation (row offsetting, [U; L] stacking, tile padding)
+    lives in repro.kernels.ops.sinnamon_candidate_scores.
     """
-    B, Lq = qv.shape
-    h = rows.shape[-1]
-    R, C = skmat.shape
-    if C % tile_c != 0:
-        raise ValueError(f"C={C} must be a multiple of tile_c={tile_c}")
-    if kp > tile_c:
-        raise ValueError(f"kp={kp} cannot exceed tile_c={tile_c}")
-    tw = tile_c // 32
-    T = C // tile_c
-    grid = (B, T)
-
-    in_specs = [
-        pl.BlockSpec((1, Lq), lambda b, c: (b, 0)),            # qv
-        pl.BlockSpec((1, Lq), lambda b, c: (b, 0)),            # pos
-        pl.BlockSpec((1, Lq, h), lambda b, c: (b, 0, 0)),      # rows
-        pl.BlockSpec((1, Lq, tw), lambda b, c: (b, 0, c)),     # qbits
-        pl.BlockSpec((1, tile_c), lambda b, c: (0, c)),        # gate
-        pl.BlockSpec((R, tile_c), lambda b, c: (0, c)),        # [U; L]
-    ]
-    kern = functools.partial(_topk_kernel, h=h, tile_c=tile_c, kp=kp,
-                             one_sided=one_sided)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=(pl.BlockSpec((1, 1, kp), lambda b, c: (b, c, 0)),
-                   pl.BlockSpec((1, 1, kp), lambda b, c: (b, c, 0))),
-        out_shape=(jax.ShapeDtypeStruct((B, T, kp), jnp.float32),
-                   jax.ShapeDtypeStruct((B, T, kp), jnp.int32)),
-        interpret=interpret,
-    )(qv, pos, rows, qbits, gate, skmat)
+    if kp > skmat.shape[1]:
+        raise ValueError(f"kp={kp} cannot exceed C={skmat.shape[1]}")
+    s = tile_scores(qv, rows, qbits, gate, skmat, tile_c=tile_c,
+                    one_sided=one_sided, interpret=interpret)
+    return topk_candidates(s, kp)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("kp", "tile_c", "one_sided",
-                                    "query_block"))
-def fused_topk_xla(
+@functools.partial(jax.jit, static_argnames=("one_sided",))
+def scores_xla(
     qv: jax.Array,               # f32[B, L]
-    pos: jax.Array,              # bool[B, L]
     rows: jax.Array,             # int32[B, L, h]  (pre-offset when one_sided)
-    qbits: jax.Array,            # uint32[B, L, W]
+    qbits: jax.Array,            # uint32[B, L, W]  (W = C/32)
     gate: jax.Array,             # f32[1, C]
     skmat: jax.Array,            # [R, C]
     *,
-    kp: int,
-    tile_c: int = DEFAULT_TILE_C_XLA,
     one_sided: bool = True,
-    query_block: int = 2,
-) -> tuple:
-    """XLA twin of :func:`sinnamon_score_topk`: same operands, same per-tile
-    math (:func:`_fused_tile_scores`), same (vals, slots)[B, T, kp] output.
+) -> jax.Array:
+    """XLA twin of :func:`tile_scores`: same operands, the same gated
+    ``f32[B, C]``, bit for bit.
 
-    The grid becomes lax.map over query blocks × lax.scan over slot tiles,
-    which is how the tile program runs fast on backends where Pallas only has
-    the (per-grid-step interpreted) validation lowering.  Query blocks bound
-    the [QB, L, TC] working set exactly like the kernel's VMEM block does.
+    The kernel's per-slot program over all slots at once: the same
+    one-sided decode, the same f32 products, and the same add order — one
+    add per coordinate, in coordinate order, from +0.  The coordinate loop
+    is unrolled, so XLA fuses the whole sum into one pass over the slots
+    without a ``[B, L, C]`` intermediate.  This is how the kernel's math
+    runs on backends where Pallas has only its interpreter (CPU serving).
     """
     B, Lq = qv.shape
     h = rows.shape[-1]
-    R, C = skmat.shape
-    if C % tile_c != 0:
-        raise ValueError(f"C={C} must be a multiple of tile_c={tile_c}")
-    if kp > tile_c:
-        raise ValueError(f"kp={kp} cannot exceed tile_c={tile_c}")
-    tw = tile_c // 32
-    T = C // tile_c
-    qb = min(query_block, B)
-    nb = (B + qb - 1) // qb
-    pad_b = nb * qb - B
-
-    def pad(x):
-        return jnp.pad(x, [(0, pad_b)] + [(0, 0)] * (x.ndim - 1))
-
-    qv_b = pad(qv).reshape(nb, qb, Lq)
-    pos_b = pad(pos).reshape(nb, qb, Lq)
-    rows_b = pad(rows).reshape(nb, qb, Lq, h)
-    qbits_b = pad(qbits).reshape(nb, qb, Lq, T, tw)
-    sk_t = jnp.moveaxis(skmat.reshape(R, T, tile_c), 1, 0)   # [T, R, TC]
-    gate_t = gate.reshape(T, tile_c)
-
-    def one_block(args):
-        bqv, bpos, brows, bqbits = args                      # [qb, ...]
-
-        def tile_step(carry, xs):
-            sk_tile, g_tile, words, base = xs
-            s = _fused_tile_scores(bqv, bpos, brows, words, g_tile, sk_tile,
-                                   h=h, one_sided=one_sided, tile_c=tile_c)
-            v, i = jax.lax.top_k(s, kp)                      # [qb, kp]
-            return carry, (v, (base * tile_c + i).astype(jnp.int32))
-
-        xs = (sk_t, gate_t, jnp.moveaxis(bqbits, 2, 0), jnp.arange(T))
-        _, (vs, ss) = jax.lax.scan(tile_step, 0, xs)         # [T, qb, kp]
-        return jnp.moveaxis(vs, 0, 1), jnp.moveaxis(ss, 0, 1)
-
-    vals, slots = jax.lax.map(one_block, (qv_b, pos_b, rows_b, qbits_b))
-    vals = vals.reshape(nb * qb, T, kp)[:B]
-    slots = slots.reshape(nb * qb, T, kp)[:B]
-    return vals, slots
-
-
-def _sorted_merge(neg: jax.Array, slots: jax.Array, width: int) -> tuple:
-    """Sort candidate rows by (neg score asc, slot asc) and keep ``width``."""
-    neg, slots = jax.lax.sort((neg, slots), dimension=-1, num_keys=2)
-    return neg[..., :width], slots[..., :width]
-
-
-def merge_tile_topk(vals: jax.Array, slots: jax.Array, kprime: int) -> tuple:
-    """Log-tree merge of per-tile candidate buffers -> global top-kprime.
-
-    vals/slots: [B, T, kp] per-tile candidates, each tile already ordered by
-    (score desc, slot asc).  Adjacent tiles are merged pairwise with a
-    two-key sort on (-score, slot), so the final [B, kprime] list carries the
-    exact (score desc, slot asc) order of ``lax.top_k`` over the dense score
-    vector — including the all--inf tail when fewer than kprime slots
-    survive the gate.  Requires T * kp >= kprime (guaranteed by the wrapper:
-    kp = min(kprime, tile_c) and T * tile_c >= C >= kprime).
-    """
-    B, T, kp = vals.shape
-    neg = -vals
-    while T > 1:
-        if T % 2:
-            # Odd tile count: add a dummy tile that sorts after everything
-            # (score -inf AND the max slot key), so it can never displace a
-            # real candidate nor perturb the -inf tie order.
-            neg = jnp.concatenate(
-                [neg, jnp.full((B, 1, kp), jnp.inf, neg.dtype)], axis=1)
-            slots = jnp.concatenate(
-                [slots, jnp.full((B, 1, kp), _SLOT_SENTINEL, slots.dtype)],
-                axis=1)
-            T += 1
-        width = min(kprime, 2 * kp)
-        neg = neg.reshape(B, T // 2, 2 * kp)
-        slots = slots.reshape(B, T // 2, 2 * kp)
-        neg, slots = _sorted_merge(neg, slots, width)
-        T //= 2
-        kp = width
-    return -neg[:, 0, :kprime], slots[:, 0, :kprime]
+    C = skmat.shape[1]
+    sk = skmat.astype(jnp.float32)
+    shifts = jnp.arange(_WORD, dtype=jnp.uint32)
+    acc = jnp.zeros((B, C), jnp.float32)
+    for t in range(Lq):
+        v = qv[:, t, None]                                  # [B, 1]
+        x = sk[rows[:, t, 0]]                               # [B, C]
+        for o in range(1, h):
+            y = sk[rows[:, t, o]]
+            if one_sided:
+                # positive coords decode U (min); negative decode L (max).
+                x = jnp.where(v > 0, jnp.minimum(x, y), jnp.maximum(x, y))
+            else:
+                x = jnp.minimum(x, y)
+        if not one_sided:
+            # positive-only engine: l == 0 exactly, so q<0 contributes q*0.
+            x = jnp.where(v > 0, x, 0.0)
+        member = ((qbits[:, t, :, None] >> shifts) & 1).reshape(B, C)
+        acc = acc + jnp.where(member != 0, v * x, 0.0)
+    return jnp.where(gate == 0.0, acc, -jnp.inf)

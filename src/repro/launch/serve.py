@@ -9,9 +9,14 @@ then serve batched queries with the anytime budget.
         [--wal runs/wal --snapshot-dir runs/snap --snapshot-every 5000 \
          --compact-threshold 0.5]
 
-``--shards N`` (N > 1) serves through the mesh-sharded streaming index on a
-host-local mesh (N forced host devices, corpus sharded over 'model'), using
-the batched `query_many` path; the default is the single-device index.
+``--shards N`` (N > 1) serves through the mesh-sharded streaming index
+(corpus sharded over 'model'), using the batched `query_many` path: on an
+accelerator over the first N chips; on the CPU platform over N forced host
+devices.  The default is the single-device index.
+
+``--docs`` at or above ``BULK_DRAW_DOCS`` draws the corpus with the
+vectorized ``synth.make_corpus_bulk`` (same law, different numbers); the
+recall oracle is the batched exact scan ``repro.eval.recall.exact_topk_ids``.
 
 ``--sketch-kind lite`` serves the §3.3 upper-bound-only half sketch and
 ``--value-dtype`` picks the quantized sketch-cell storage — the paper's
@@ -98,6 +103,9 @@ from __future__ import annotations
 import argparse
 import os
 
+#: --docs at or above this draw the corpus with the vectorized bulk draw.
+BULK_DRAW_DOCS = 1 << 16
+
 
 def parse_args(argv=None):
     ap = argparse.ArgumentParser()
@@ -132,7 +140,9 @@ def parse_args(argv=None):
                          "(default: REPRO_SCORE_BACKEND env or 'pallas', "
                          "the fused tiled-top-k kernel)")
     ap.add_argument("--shards", type=int, default=1,
-                    help=">1: sharded streaming index on a host-local mesh")
+                    help=">1: sharded streaming index over N devices: the "
+                         "first N chips on an accelerator; on the CPU "
+                         "platform N forced host devices")
     ap.add_argument("--device-budget-mb", type=float, default=None,
                     metavar="MB",
                     help="per-device byte budget for raw vector rows; "
@@ -185,7 +195,8 @@ def parse_args(argv=None):
                          "(per-stage histograms); default 32 when metrics "
                          "or the event log are enabled, 0 = off")
     ap.add_argument("--profile-dir", default=None, metavar="DIR",
-                    help="capture a jax.profiler trace of the query loop")
+                    help="capture a jax.profiler trace of the query loop "
+                         "(the launcher fails if the trace cannot start)")
     ap.add_argument("--hold-seconds", type=float, default=0.0, metavar="S",
                     help="keep the process (and metrics endpoint) alive "
                          "this long after the query loop")
@@ -261,7 +272,9 @@ def _check_launch_params(args) -> None:
     import json
     import sys
 
-    params = {"dataset": args.dataset, "docs": args.docs, "m": args.m,
+    params = {"dataset": args.dataset, "docs": args.docs,
+              "draw": "bulk" if args.docs >= BULK_DRAW_DOCS else "loop",
+              "m": args.m,
               "h": args.h, "index_buckets": args.index_buckets,
               "sketch_kind": args.sketch_kind,
               "value_dtype": args.value_dtype,
@@ -290,18 +303,20 @@ def _check_launch_params(args) -> None:
 def main():
     args = parse_args()
     if args.shards > 1:
-        # Must happen before jax initialises its backends; append so any
-        # user-provided XLA_FLAGS survive.
+        # CPU shards are forced host devices (the flag affects only the CPU
+        # platform).  Must happen before jax initialises its backends;
+        # append so any user-provided XLA_FLAGS survive.
         flag = f"--xla_force_host_platform_device_count={args.shards}"
         prev = os.environ.get("XLA_FLAGS", "")
         if "xla_force_host_platform_device_count" not in prev:
             os.environ["XLA_FLAGS"] = f"{prev} {flag}".strip()
 
+    import jax
     import numpy as np
 
     from repro.api import DurabilityConfig, IndexConfig, open_index
-    from repro.core.linscan import brute_force_topk
     from repro.data import synth
+    from repro.eval.recall import exact_topk_ids
     from repro.obs import (
         EventLog,
         FlightRecorder,
@@ -313,8 +328,10 @@ def main():
         set_recorder,
     )
     from repro.obs.instrument import install_recorder_gauges
+    from repro.runtime import enable_compile_cache
     from repro.serving.serve import QueryServer
 
+    enable_compile_cache()
     if args.failpoints:
         from repro.fault import FailpointRegistry, set_failpoints
         set_failpoints(FailpointRegistry(seed=args.failpoint_seed)
@@ -356,7 +373,9 @@ def main():
               f"readiness: /readyz, debug: /debug/requests /debug/slo)")
 
     ds = synth.DATASETS[args.dataset]
-    idx, val = synth.make_corpus(0, ds, args.docs, pad=256)
+    draw = (synth.make_corpus_bulk if args.docs >= BULK_DRAW_DOCS
+            else synth.make_corpus)
+    idx, val = draw(0, ds, args.docs, pad=256)
     qi, qv = synth.make_queries(1, ds, args.queries, pad=96)
     cap = ((args.docs + 31) // 32) * 32
     sketch_kind, cell_dtype = args.sketch_kind, args.value_dtype
@@ -410,6 +429,9 @@ def main():
     for lo in range(0, len(todo), 2048):
         chunk = todo[lo:lo + 2048]
         index.insert_many(chunk, idx[chunk], val[chunk])
+        # Inserts do not donate the state: sync so that at most two copies
+        # of a multi-GB state are alive at once.
+        jax.block_until_ready(index.state)
     n_shards = args.shards if args.shards > 1 else 1
     print(f"indexed {index.size} docs over {n_shards} shard(s)")
     if args.wal and args.snapshot_dir:
@@ -423,26 +445,19 @@ def main():
     ready.mark("engine", True)      # built/recovered: ready to serve
     if slo_monitor is not None:
         slo_monitor.start()
-    profiling = False
     if args.profile_dir:
-        import jax
-        try:
-            jax.profiler.start_trace(args.profile_dir)
-            profiling = True
-        except Exception as e:                          # noqa: BLE001
-            print(f"profiler unavailable ({e!r}); continuing without")
-    recalls = []
+        jax.profiler.start_trace(args.profile_dir)
+    served = []
     for lo in range(0, args.queries, args.query_batch):
         hi = min(lo + args.query_batch, args.queries)
         ids, _ = server.query_many(qi[lo:hi], qv[lo:hi])
-        for b in range(lo, hi):
-            ids0, _ = brute_force_topk(idx, val, qi[b], qv[b], ds.n, args.k)
-            recalls.append(
-                len(set(ids[b - lo].tolist()) & set(ids0.tolist())) / args.k)
-    if profiling:
-        import jax
+        served.extend(ids)
+    if args.profile_dir:
         jax.profiler.stop_trace()
         print(f"profiler trace written to {args.profile_dir}")
+    truth = exact_topk_ids(idx, val, qi, qv, ds.n, args.k)
+    recalls = [len(set(ids.tolist()) & set(t.tolist())) / args.k
+               for ids, t in zip(served, truth)]
     lat = server.latency_percentiles()
     print(f"recall@{args.k}={np.mean(recalls):.3f}  "
           f"p50={lat['p50']:.1f}ms p90={lat['p90']:.1f}ms "

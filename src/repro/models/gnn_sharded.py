@@ -30,7 +30,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models import gnn, sh
@@ -298,13 +297,13 @@ def forward_sharded(params, g: GraphBatch, cfg: GNNConfig, mesh: Mesh):
         return f[None]
 
     pspecs = _param_pspecs(cfg)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspecs, P(), P(None, data_ax), P(None, data_ax),
                   P(None, data_ax, None)),
         out_specs=P(None, data_ax if isinstance(data_ax, str) else data_ax,
                     None, model_ax),
-        check_rep=False)
+        check_vma=False)
     # edges get a leading singleton axis so shard_map splits dim 1 (= edges)
     f = fn(params, g.node_feat, g.edge_src[None], g.edge_dst[None],
            g.edge_vec[None])

@@ -55,20 +55,11 @@ TIERED_QUERY_STAGES = ("admission", "sketch_scan", "prefetch", "rerank")
 # operand prep, identical kernels, identical rerank.
 
 @partial(jax.jit, static_argnums=(1, 4, 5))
-def _tile_candidates(state, spec, q_idx, q_val, kprime, budget):
-    from repro.kernels import ops as _ops
-    return _ops.sinnamon_tile_topk(state, spec, q_idx, q_val, kprime,
-                                   budget=budget, ok=state.active)
-
-
-@partial(jax.jit, static_argnums=(2,))
-def _merge_candidates(vals, slots, kprime):
-    from repro.kernels import sinnamon_score as _sinn
-    return _sinn.merge_tile_topk(vals, slots, kprime)
-
-
-@partial(jax.jit, static_argnums=(1, 4, 5))
 def _gated_scores(state, spec, q_idx, q_val, budget, backend):
+    if backend == "pallas":
+        from repro.kernels import ops as _ops
+        return _ops.sinnamon_candidate_scores(state, spec, q_idx, q_val,
+                                              budget=budget, ok=state.active)
     s = eng.score_batch(state, spec, q_idx, q_val, budget,
                         grouped=(backend == "grouped"))
     return jnp.where(state.active[None, :], s, -jnp.inf)
@@ -76,8 +67,8 @@ def _gated_scores(state, spec, q_idx, q_val, budget, backend):
 
 @partial(jax.jit, static_argnums=(1,))
 def _dense_topk(scores, kprime):
-    vals, slots = jax.lax.top_k(scores, kprime)
-    return vals, slots.astype(jnp.int32)
+    from repro.kernels import sinnamon_score as _sinn
+    return _sinn.topk_candidates(scores, kprime)
 
 
 @partial(jax.jit, static_argnums=(1,))
@@ -330,23 +321,13 @@ class QueryServer:
             k = min(self.k, kprime)
             q_idx = jnp.asarray(q_idx)
             q_val = jnp.asarray(q_val)
-        if backend == "pallas":
-            with trace.span("sketch_scan"):
-                tile_vals, tile_slots = _tile_candidates(
-                    state, spec, q_idx, q_val, kprime, self.budget)
-                jax.block_until_ready(tile_vals)
-            with trace.span("topk_merge"):
-                cand_scores, cand_slots = _merge_candidates(
-                    tile_vals, tile_slots, kprime)
-                jax.block_until_ready(cand_scores)
-        else:
-            with trace.span("sketch_scan"):
-                scores = _gated_scores(state, spec, q_idx, q_val,
-                                       self.budget, backend)
-                jax.block_until_ready(scores)
-            with trace.span("topk_merge"):
-                cand_scores, cand_slots = _dense_topk(scores, kprime)
-                jax.block_until_ready(cand_scores)
+        with trace.span("sketch_scan"):
+            scores = _gated_scores(state, spec, q_idx, q_val, self.budget,
+                                   backend)
+            jax.block_until_ready(scores)
+        with trace.span("topk_merge"):
+            cand_scores, cand_slots = _dense_topk(scores, kprime)
+            jax.block_until_ready(cand_scores)
         with trace.span("rerank"):
             ids, top_scores, _ = _rerank(state, k, cand_scores, cand_slots,
                                          q_idx, q_val)

@@ -28,7 +28,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import engine as eng
@@ -132,11 +131,11 @@ def make_search_step(mesh: Mesh, local_spec: eng.EngineSpec, *,
         exact = jnp.where(jnp.isneginf(ub), -jnp.inf, exact)
         return _merge_local_exact(mesh, corpus, state, exact, slots, k)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_search, mesh=mesh,
         in_specs=(state_pspecs(mesh, local_spec.upper_only), qspec, qspec),
         out_specs=(qspec, qspec, qspec),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(sharded)
 
@@ -162,10 +161,10 @@ def make_insert_step(mesh: Mesh, local_spec: eng.EngineSpec):
         return eng.insert_batch_masked(state, local_spec, slots[0], eids[0],
                                        idx[0], val[0], mask[0])
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_insert, mesh=mesh,
         in_specs=(sspec, uspec, uspec, uspec, uspec, uspec),
-        out_specs=sspec, check_rep=False)
+        out_specs=sspec, check_vma=False)
     return jax.jit(sharded)
 
 
@@ -178,10 +177,10 @@ def make_delete_step(mesh: Mesh, local_spec: eng.EngineSpec):
     def local_delete(state, slots, mask):
         return eng.delete_batch_masked(state, local_spec, slots[0], mask[0])
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_delete, mesh=mesh,
         in_specs=(sspec, uspec, uspec),
-        out_specs=sspec, check_rep=False)
+        out_specs=sspec, check_vma=False)
     return jax.jit(sharded)
 
 
@@ -199,8 +198,8 @@ def make_grow_step(mesh: Mesh, local_spec: eng.EngineSpec,
     def local_grow(state):
         return eng.grow_state(state, local_spec, new_spec)
 
-    sharded = shard_map(local_grow, mesh=mesh, in_specs=(sspec_in,),
-                        out_specs=sspec_in, check_rep=False)
+    sharded = jax.shard_map(local_grow, mesh=mesh, in_specs=(sspec_in,),
+                            out_specs=sspec_in, check_vma=False)
     return jax.jit(sharded), new_spec
 
 
@@ -212,8 +211,8 @@ def make_compact_step(mesh: Mesh, local_spec: eng.EngineSpec):
     def local_compact(state):
         return eng.compact_state(state, local_spec)
 
-    sharded = shard_map(local_compact, mesh=mesh, in_specs=(sspec,),
-                        out_specs=sspec, check_rep=False)
+    sharded = jax.shard_map(local_compact, mesh=mesh, in_specs=(sspec,),
+                            out_specs=sspec, check_vma=False)
     return jax.jit(sharded)
 
 
@@ -225,14 +224,24 @@ def make_drift_step(mesh: Mesh, local_spec: eng.EngineSpec):
     def local_drift(state):
         return eng.slot_drift(state, local_spec)
 
-    sharded = shard_map(local_drift, mesh=mesh, in_specs=(sspec,),
-                        out_specs=P(c), check_rep=False)
+    sharded = jax.shard_map(local_drift, mesh=mesh, in_specs=(sspec,),
+                            out_specs=P(c), check_vma=False)
     return jax.jit(sharded)
 
 
 def shard_state(state: eng.SinnamonState, mesh: Mesh):
     """Place a host-built (global) state onto the mesh."""
     return jax.device_put(state, state_shardings(mesh, state.l is None))
+
+
+def init_sharded_state(global_spec: eng.EngineSpec, mesh: Mesh, *,
+                       store_rows: Optional[int] = None):
+    """A fresh global state created directly in its shards: no device ever
+    holds more than its own slice (a global state built on one device
+    first would not fit it at multi-chip corpus sizes)."""
+    init = jax.jit(lambda: eng.init(global_spec, store_rows=store_rows),
+                   out_shardings=state_shardings(mesh, global_spec.upper_only))
+    return init()
 
 
 # ---------------------------------------------------------------------------
@@ -271,10 +280,10 @@ def make_candidates_step(mesh: Mesh, local_spec: eng.EngineSpec, *,
                                         budget, backend=backend)
         return ub[None], slots[None]
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_cand, mesh=mesh,
         in_specs=(state_pspecs(mesh, local_spec.upper_only), qspec, qspec),
-        out_specs=(bspec, bspec), check_rep=False)
+        out_specs=(bspec, bspec), check_vma=False)
     return jax.jit(sharded)
 
 
@@ -294,10 +303,10 @@ def make_rerank_rows_step(mesh: Mesh, local_spec: eng.EngineSpec, *, k: int):
         exact = jnp.where(jnp.isneginf(ub), -jnp.inf, exact)
         return _merge_local_exact(mesh, corpus, state, exact, slots, k)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_rerank, mesh=mesh,
         in_specs=(sspec, bspec, bspec, bspec, bspec, qspec, qspec),
-        out_specs=(qspec, qspec, qspec), check_rep=False)
+        out_specs=(qspec, qspec, qspec), check_vma=False)
     return jax.jit(sharded)
 
 
@@ -312,10 +321,10 @@ def make_delete_rows_step(mesh: Mesh, local_spec: eng.EngineSpec):
         return eng.delete_batch_rows(state, local_spec, slots[0], idx[0],
                                      mask[0])
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_delete, mesh=mesh,
         in_specs=(sspec, uspec, uspec, uspec),
-        out_specs=sspec, check_rep=False)
+        out_specs=sspec, check_vma=False)
     return jax.jit(sharded)
 
 
@@ -330,10 +339,10 @@ def make_compact_rows_step(mesh: Mesh, local_spec: eng.EngineSpec):
         return eng.compact_slots_rows(state, local_spec, slots[0], idx[0],
                                       val[0], mask[0])
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_compact, mesh=mesh,
         in_specs=(sspec, uspec, uspec, uspec, uspec),
-        out_specs=sspec, check_rep=False)
+        out_specs=sspec, check_vma=False)
     return jax.jit(sharded)
 
 
@@ -347,10 +356,10 @@ def make_drift_rows_step(mesh: Mesh, local_spec: eng.EngineSpec):
         return eng.slot_drift_rows(state, local_spec, slots[0], idx[0],
                                    val[0])[None]
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_drift, mesh=mesh,
         in_specs=(sspec, uspec, uspec, uspec),
-        out_specs=P(c), check_rep=False)
+        out_specs=P(c), check_vma=False)
     return jax.jit(sharded)
 
 
@@ -392,17 +401,17 @@ class ShardedSinnamonIndex:
         self.update_block = update_block
         global_spec = dataclasses.replace(
             spec, capacity=spec.capacity * self.n_shards)
-        self.state = shard_state(self._init_state(global_spec), mesh)
+        self.state = init_sharded_state(global_spec, mesh,
+                                        store_rows=self._store_rows)
         self._free = [list(range(spec.capacity - 1, -1, -1))
                       for _ in range(self.n_shards)]
         self._id2slot: dict[int, tuple[int, int]] = {}
         self._steps: dict = {}
         self._obs = eng._WritePathMetrics()
 
-    def _init_state(self, global_spec: eng.EngineSpec) -> eng.SinnamonState:
-        """Fresh host-built global state; the tiered subclass swaps in a
-        zero-row placeholder store here."""
-        return eng.init(global_spec)
+    #: Raw-store rows of a fresh state (None = one per slot); the tiered
+    #: subclass keeps a zero-row placeholder on the device.
+    _store_rows: Optional[int] = None
 
     # -- routing ------------------------------------------------------------
     def route(self, ext_id: int) -> int:
@@ -651,8 +660,7 @@ class TieredShardedSinnamonIndex(ShardedSinnamonIndex):
                            device=devices[s])
             for s in range(self.n_shards)]
 
-    def _init_state(self, global_spec: eng.EngineSpec) -> eng.SinnamonState:
-        return eng.init(global_spec, store_rows=0)
+    _store_rows = 0
 
     # -- streaming updates ---------------------------------------------------
     def _apply_insert_block(self, slots, eids, idxs, vals, mask) -> None:
@@ -734,32 +742,27 @@ class TieredShardedSinnamonIndex(ShardedSinnamonIndex):
 
     def _gather_global(self, slots_np: np.ndarray):
         """Per-shard chunk-cache gathers assembled into global [S, B, kl, P]
-        arrays sharded over the corpus axes.  Fast path: each shard's rows
-        are already on its own device, so the global array is assembled
-        without host round-trips; falls back to a host stack + device_put
-        when the batch is data-sharded."""
+        arrays sharded over the corpus axes.  Each shard's rows are already
+        on its own device, so the global array is assembled without host
+        round-trips — unless the batch is data-sharded, where one shard's
+        block spans several devices and goes through a host stack +
+        device_put."""
         S, B, kl = slots_np.shape
         Pw = self.spec.max_nnz
         pieces = [self.tiers[s].gather_rows(slots_np[s].reshape(-1))
                   for s in range(S)]
         sh = NamedSharding(self.mesh, _block_spec(self.mesh))
         shape = (S, B, kl, Pw)
-        try:
-            if any(self.mesh.shape[a] != 1
-                   for a in meshlib.batch_axes(self.mesh)):
-                raise ValueError("data-sharded batch needs the host path")
-            ridx = jax.make_array_from_single_device_arrays(
-                shape, sh, [p[0].reshape(1, B, kl, Pw) for p in pieces])
-            rval = jax.make_array_from_single_device_arrays(
-                shape, sh, [p[1].reshape(1, B, kl, Pw) for p in pieces])
-        except Exception:                                  # noqa: BLE001
-            ridx = jax.device_put(
-                np.stack([np.asarray(p[0]).reshape(B, kl, Pw)
-                          for p in pieces]), sh)
-            rval = jax.device_put(
-                np.stack([np.asarray(p[1]).reshape(B, kl, Pw)
-                          for p in pieces]), sh)
-        return ridx, rval
+        if any(self.mesh.shape[a] != 1
+               for a in meshlib.batch_axes(self.mesh)):
+            return tuple(
+                jax.device_put(np.stack([np.asarray(p[j]).reshape(B, kl, Pw)
+                                         for p in pieces]), sh)
+                for j in (0, 1))
+        return tuple(
+            jax.make_array_from_single_device_arrays(
+                shape, sh, [p[j].reshape(1, B, kl, Pw) for p in pieces])
+            for j in (0, 1))
 
     # -- capacity / maintenance ----------------------------------------------
     def grow(self, new_local_capacity: Optional[int] = None) -> None:

@@ -89,7 +89,6 @@ def test_compressed_psum_shard_map():
         import sys; sys.path.insert(0, "src")
         import functools
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.distributed import mesh as meshlib
         from repro.optim import compress
@@ -101,7 +100,7 @@ def test_compressed_psum_shard_map():
             out, r2 = compress.compressed_psum({"w": g[0]}, {"w": r[0]},
                                                "pod")
             return out["w"][None], r2["w"][None]
-        fn = shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
+        fn = jax.shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
                        out_specs=(P("pod"), P("pod")))
         out, _ = fn(g, res)
         want = np.asarray(g).mean(0)
