@@ -1,17 +1,18 @@
 """CI check: launch the demo server with --metrics-port and validate /metrics.
 
-Boots ``repro.launch.serve`` as a subprocess with a metrics endpoint, an
-event log and tracing enabled, then:
+Boots ``repro.launch.serve`` as a subprocess with a metrics endpoint and
+an event log, then:
 
-1. polls ``/metrics`` until the per-stage and latency histogram families
-   appear (i.e. the server actually served traced queries),
+1. polls ``/metrics`` until the query counter and latency histogram
+   families appear (i.e. the server actually served queries),
 2. parses the full Prometheus exposition with
    ``repro.obs.metrics.parse_exposition`` (malformed lines raise),
 3. asserts the required metric families from the ISSUE acceptance list are
-   present (per-stage latency, WAL-independent engine health, byte gauges),
+   present (query latency, WAL-independent engine health, byte gauges),
 4. fetches ``/metrics.json`` and checks it is valid JSON with the same
    metric names,
-5. checks the event log contains parseable ``query`` events with spans,
+5. checks the event log contains parseable ``query`` events carrying
+   their batch's trace stages (``device`` with ``launch`` and ``fetch``),
 6. hits the ISSUE 8 surfaces on the same port — ``/readyz`` (must be 200
    with per-check detail once the engine is built), ``/debug/requests``
    (flight-recorder ring + stats schema), and ``/debug/slo`` (declared
@@ -37,16 +38,15 @@ for p in (_ROOT, os.path.join(_ROOT, "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-# Families the endpoint must expose once a traced query has been served.
+# Families the endpoint must expose once a query has been served.
 REQUIRED = (
     "repro_query_latency_ms_count",
-    "repro_query_stage_ms_count",
     "repro_queries_total",
     "repro_engine_live_docs",
     "repro_engine_bytes",
     "repro_engine_ops_total",
 )
-_READY_MARKERS = ("repro_query_stage_ms", "repro_query_latency_ms_count")
+_READY_MARKERS = ("repro_queries_total", "repro_query_latency_ms_count")
 _TIMEOUT_S = 240.0
 
 
@@ -73,7 +73,7 @@ def main() -> None:
     cmd = [sys.executable, "-m", "repro.launch.serve",
            "--docs", "512", "--queries", "16", "--query-batch", "8",
            "--kprime", "64", "--metrics-port", str(port),
-           "--event-log", event_log, "--trace-every", "2",
+           "--event-log", event_log,
            "--hold-seconds", "600"]
     print(f"+ {' '.join(cmd)}")
     proc = subprocess.Popen(cmd, env=env, cwd=_ROOT,
@@ -106,10 +106,7 @@ def main() -> None:
         missing = [m for m in REQUIRED if m not in names]
         if missing:
             raise RuntimeError(f"missing metric families: {missing}")
-        stages = sorted({dict(labels).get("stage")
-                         for name, labels in flat
-                         if name == "repro_query_stage_ms_count"})
-        print(f"/metrics OK: {len(flat)} series, stages={stages}")
+        print(f"/metrics OK: {len(flat)} series")
 
         doc = json.loads(_fetch(base + "/metrics.json"))
         missing = [m for m in ("repro_query_latency_ms",
@@ -122,13 +119,15 @@ def main() -> None:
 
         with open(event_log) as f:
             events = [json.loads(line) for line in f if line.strip()]
-        traced = [e for e in events
-                  if e["event"] == "query" and e.get("spans")]
+        traced = [e for e in events if e["event"] == "query"
+                  and {"device", "launch", "fetch"}
+                  <= {s["stage"] for s in e.get("stages") or ()}]
         if not traced:
-            raise RuntimeError(f"no traced query events in {event_log}; "
-                               f"saw {[e['event'] for e in events][:20]}")
-        print(f"event log OK: {len(events)} events, {len(traced)} traced; "
-              f"sample spans={[s['stage'] for s in traced[0]['spans']]}")
+            raise RuntimeError(f"no query events with device/launch/fetch "
+                               f"stages in {event_log}; saw "
+                               f"{[e['event'] for e in events][:20]}")
+        print(f"event log OK: {len(events)} events, {len(traced)} with "
+              f"stages={[s['stage'] for s in traced[0]['stages']]}")
 
         ready = json.loads(_fetch(base + "/readyz"))
         if ready.get("ready") is not True:

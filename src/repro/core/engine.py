@@ -40,6 +40,7 @@ import numpy as np
 
 from repro.core import bitindex, sketch
 from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 from repro.storage import vecstore
 
 Array = jax.Array
@@ -546,9 +547,10 @@ def score(state: SinnamonState, spec: EngineSpec, q_idx: Array, q_val: Array,
     coordinates are scored (deterministic adaptation of the paper's wall-clock
     budget T; see DESIGN.md §6).  None = all coordinates (T = ∞).
     """
-    q_idx, q_val = _sorted_query(q_idx, q_val)
+    with jax.named_scope("operands"):
+        q_idx, q_val = _sorted_query(q_idx, q_val)
+        rows = coord_rows(spec, q_idx)      # bitmap rows in SORTED order
     steps = q_idx.shape[0] if budget is None else min(budget, q_idx.shape[0])
-    rows = coord_rows(spec, q_idx)          # bitmap rows in SORTED order
 
     def body(t, scores):
         j = q_idx[t]
@@ -559,8 +561,9 @@ def score(state: SinnamonState, spec: EngineSpec, q_idx: Array, q_val: Array,
         memb = bitindex.row_mask(state.bits, jnp.maximum(rows[t], 0))
         return scores + jnp.where(memb & (j >= 0), contrib, 0.0)
 
-    scores = jnp.zeros((spec.capacity,), jnp.float32)
-    return jax.lax.fori_loop(0, steps, body, scores)
+    with jax.named_scope("scan"):
+        scores = jnp.zeros((spec.capacity,), jnp.float32)
+        return jax.lax.fori_loop(0, steps, body, scores)
 
 
 def score_grouped(state: SinnamonState, spec: EngineSpec, q_idx: Array,
@@ -572,22 +575,25 @@ def score_grouped(state: SinnamonState, spec: EngineSpec, q_idx: Array,
     XLA keep the candidate tile resident instead of re-walking scores[C] per
     coordinate (psi_q x fewer accumulator read-modify-writes).
     """
-    q_idx, q_val = _sorted_query(q_idx, q_val)
-    L = q_idx.shape[0] if budget is None else min(budget, q_idx.shape[0])
-    j = q_idx[:L]
-    v = q_val[:L].astype(jnp.float32)
-    safe = jnp.where(j >= 0, j, 0)
-    rows = state.mappings[:, safe]                           # [h, L]
-    ub = jnp.min(state.u[rows].astype(jnp.float32), axis=0)  # [L, C]
-    if state.l is None:
-        lb = jnp.zeros_like(ub)
-    else:
-        lb = jnp.max(state.l[rows].astype(jnp.float32), axis=0)
-    contrib = jnp.where(v[:, None] > 0, v[:, None] * ub, v[:, None] * lb)
-    bit_rows = jnp.maximum(coord_rows(spec, j), 0)
-    memb = bitindex.unpack_row(state.bits[bit_rows])         # [L, C]
-    contrib = jnp.where(memb & (j >= 0)[:, None], contrib, 0.0)
-    return jnp.sum(contrib, axis=0)
+    with jax.named_scope("operands"):
+        q_idx, q_val = _sorted_query(q_idx, q_val)
+        L = q_idx.shape[0] if budget is None else min(budget, q_idx.shape[0])
+        j = q_idx[:L]
+        v = q_val[:L].astype(jnp.float32)
+        safe = jnp.where(j >= 0, j, 0)
+        rows = state.mappings[:, safe]                       # [h, L]
+        bit_rows = jnp.maximum(coord_rows(spec, j), 0)
+    with jax.named_scope("scan"):
+        ub = jnp.min(state.u[rows].astype(jnp.float32), axis=0)  # [L, C]
+        if state.l is None:
+            lb = jnp.zeros_like(ub)
+        else:
+            lb = jnp.max(state.l[rows].astype(jnp.float32), axis=0)
+        contrib = jnp.where(v[:, None] > 0, v[:, None] * ub,
+                            v[:, None] * lb)
+        memb = bitindex.unpack_row(state.bits[bit_rows])     # [L, C]
+        contrib = jnp.where(memb & (j >= 0)[:, None], contrib, 0.0)
+        return jnp.sum(contrib, axis=0)
 
 
 def score_batch(state, spec, q_idx, q_val, budget=None, grouped=False
@@ -611,6 +617,10 @@ def topk_candidates(state: SinnamonState, spec: EngineSpec, q_idx: Array,
     backend: ``reference | grouped | pallas`` (None -> the process default,
     see repro.kernels.ops.resolve_backend).  ``score_fn`` overrides the
     backend with a custom per-query dense scorer (legacy hook).
+
+    Every backend runs its query sort and row gathers in the named scope
+    ``operands``, its scoring pass in ``scan`` and the gate plus the top-k
+    in ``topk``: the names a profiled program's device ops carry.
     """
     from repro.kernels import ops as _ops   # deferred: kernels import engine
 
@@ -622,9 +632,10 @@ def topk_candidates(state: SinnamonState, spec: EngineSpec, q_idx: Array,
     fn = score_fn if score_fn is not None else (
         score_grouped if backend == "grouped" else score)
     s = jax.vmap(lambda i, v: fn(state, spec, i, v, budget))(q_idx, q_val)
-    s = jnp.where(ok[None, :], s, -jnp.inf)
-    vals, slots = jax.lax.top_k(s, kprime)
-    return vals, slots.astype(jnp.int32)
+    with jax.named_scope("topk"):
+        s = jnp.where(ok[None, :], s, -jnp.inf)
+        vals, slots = jax.lax.top_k(s, kprime)
+        return vals, slots.astype(jnp.int32)
 
 
 def search(state: SinnamonState, spec: EngineSpec, q_idx: Array, q_val: Array,
@@ -656,17 +667,18 @@ def rerank_topk(state, cand_scores, cand_slots, q_idx, q_val, k):
 
     Gathers only the candidate CSR rows (no dense R^n query), masks slots
     whose upper bound was gated to -inf, and returns the exact top-k:
-    (packed ids uint32[B, k, 2], scores f32[B, k], slots int32[B, k]).
-    Shared by :func:`search_batch` and the staged serving path so both
-    rerank bit-identically.
+    (packed ids uint32[B, k, 2], scores f32[B, k], slots int32[B, k]),
+    all in the named scope ``rerank``.
     """
-    exact = jax.vmap(
-        lambda s_, i, v: vecstore.exact_scores_sparse(state.store, s_, i, v)
-    )(cand_slots, q_idx, q_val)
-    exact = jnp.where(jnp.isneginf(cand_scores), -jnp.inf, exact)
-    top_scores, pos = jax.lax.top_k(exact, k)
-    slots = jnp.take_along_axis(cand_slots, pos, axis=-1)
-    return state.ids[slots], top_scores, slots
+    with jax.named_scope("rerank"):
+        exact = jax.vmap(
+            lambda s_, i, v: vecstore.exact_scores_sparse(state.store, s_,
+                                                          i, v)
+        )(cand_slots, q_idx, q_val)
+        exact = jnp.where(jnp.isneginf(cand_scores), -jnp.inf, exact)
+        top_scores, pos = jax.lax.top_k(exact, k)
+        slots = jnp.take_along_axis(cand_slots, pos, axis=-1)
+        return state.ids[slots], top_scores, slots
 
 
 def rerank_topk_rows(state, cand_scores, cand_slots, rows_idx, rows_val,
@@ -681,13 +693,14 @@ def rerank_topk_rows(state, cand_scores, cand_slots, rows_idx, rows_val,
     """
     B, kp = cand_slots.shape
     Pw = rows_idx.shape[-1]
-    ri = rows_idx.reshape(B, kp, Pw)
-    rv = rows_val.reshape(B, kp, Pw)
-    exact = jax.vmap(vecstore.exact_scores_rows)(ri, rv, q_idx, q_val)
-    exact = jnp.where(jnp.isneginf(cand_scores), -jnp.inf, exact)
-    top_scores, pos = jax.lax.top_k(exact, k)
-    slots = jnp.take_along_axis(cand_slots, pos, axis=-1)
-    return state.ids[slots], top_scores, slots
+    with jax.named_scope("rerank"):
+        ri = rows_idx.reshape(B, kp, Pw)
+        rv = rows_val.reshape(B, kp, Pw)
+        exact = jax.vmap(vecstore.exact_scores_rows)(ri, rv, q_idx, q_val)
+        exact = jnp.where(jnp.isneginf(cand_scores), -jnp.inf, exact)
+        top_scores, pos = jax.lax.top_k(exact, k)
+        slots = jnp.take_along_axis(cand_slots, pos, axis=-1)
+        return state.ids[slots], top_scores, slots
 
 
 def rerank_single_rows(state, cand_scores, cand_slots, rows_idx, rows_val,
@@ -892,15 +905,21 @@ class SinnamonIndex:
     def search_many(self, q_idx, q_val, k: int, kprime: Optional[int] = None,
                     budget: Optional[int] = None, filter_mask=None,
                     score_fn=None, backend: Optional[str] = None):
-        """Batched search: q_idx/q_val are [B, Lq]; one jit dispatch total."""
+        """Batched search: q_idx/q_val are [B, Lq]; one jit dispatch total.
+
+        Records ``launch`` (operands to the device, the jitted call) and
+        ``fetch`` (answers to the host) into the thread's active trace
+        context (`repro.obs.trace.stage`)."""
         kprime = kprime if kprime is not None else max(5 * k, k)
         kprime = min(kprime, self.spec.capacity)
         k = min(k, kprime)
-        ids, scores, _ = self._search_many(
-            self.state, self.spec, jnp.asarray(q_idx), jnp.asarray(q_val),
-            k, kprime, budget, filter_mask, score_fn=score_fn,
-            backend=self._backend(backend))
-        return unpack_ids64(np.asarray(ids)), np.asarray(scores)
+        with obs_trace.stage("launch"):
+            ids, scores, _ = self._search_many(
+                self.state, self.spec, jnp.asarray(q_idx),
+                jnp.asarray(q_val), k, kprime, budget, filter_mask,
+                score_fn=score_fn, backend=self._backend(backend))
+        with obs_trace.stage("fetch"):
+            return unpack_ids64(np.asarray(ids)), np.asarray(scores)
 
     def search_many_sketch(self, q_idx, q_val, k: int,
                            budget: Optional[int] = None,
@@ -909,10 +928,13 @@ class SinnamonIndex:
         serving path.  Scores are sketch UPPER BOUNDS, not inner products
         — see :func:`search_batch_sketch`."""
         k = min(k, self.spec.capacity)
-        ids, ub, _ = self._search_many_sketch(
-            self.state, self.spec, jnp.asarray(q_idx), jnp.asarray(q_val),
-            k, budget, backend=self._backend(backend))
-        return unpack_ids64(np.asarray(ids)), np.asarray(ub)
+        with obs_trace.stage("launch"):
+            ids, ub, _ = self._search_many_sketch(
+                self.state, self.spec, jnp.asarray(q_idx),
+                jnp.asarray(q_val), k, budget,
+                backend=self._backend(backend))
+        with obs_trace.stage("fetch"):
+            return unpack_ids64(np.asarray(ids)), np.asarray(ub)
 
     def _backend(self, backend) -> str:
         """Resolve the backend OUTSIDE jit so the default binds at call
@@ -1102,18 +1124,25 @@ class TieredSinnamonIndex(SinnamonIndex):
                     score_fn=None, backend: Optional[str] = None):
         """Two dispatches: sketch-scan candidates, then rows-based rerank
         fed by the chunk cache (the ``[B, k']`` slot sync between them is
-        what drives promotion)."""
+        what drives promotion).  Each dispatch records its ``launch`` and
+        ``fetch`` stages, and the chunk-cache gather a ``promote`` stage."""
         kprime = kprime if kprime is not None else max(5 * k, k)
         kprime = min(kprime, self.spec.capacity)
         k = min(k, kprime)
-        qi, qv = jnp.asarray(q_idx), jnp.asarray(q_val)
-        ub, slots = self._cand(self.state, self.spec, qi, qv, kprime, budget,
-                               filter_mask, score_fn=score_fn,
-                               backend=self._backend(backend))
-        ridx, rval = self.tiered.gather_rows(np.asarray(slots).reshape(-1))
-        ids, scores, _ = self._rerank_rows(self.state, ub, slots, ridx, rval,
-                                           qi, qv, k)
-        return unpack_ids64(np.asarray(ids)), np.asarray(scores)
+        with obs_trace.stage("launch"):
+            qi, qv = jnp.asarray(q_idx), jnp.asarray(q_val)
+            ub, slots = self._cand(self.state, self.spec, qi, qv, kprime,
+                                   budget, filter_mask, score_fn=score_fn,
+                                   backend=self._backend(backend))
+        with obs_trace.stage("fetch"):
+            slots_np = np.asarray(slots).reshape(-1)
+        with obs_trace.stage("promote"):
+            ridx, rval = self.tiered.gather_rows(slots_np)
+        with obs_trace.stage("launch"):
+            ids, scores, _ = self._rerank_rows(self.state, ub, slots, ridx,
+                                               rval, qi, qv, k)
+        with obs_trace.stage("fetch"):
+            return unpack_ids64(np.asarray(ids)), np.asarray(scores)
 
     # -- capacity / maintenance ----------------------------------------------
     def grow(self, new_capacity: int) -> None:

@@ -134,10 +134,11 @@ def prepare_fused_operands(state, q_idx, q_val, budget=None, spec=None):
     +m, so the fused path reads each sketch cell ONE-SIDED — half the decode
     work of the reference scorer.
     """
-    qv, rows, qbits = prepare_query_operands(state, q_idx, q_val, budget,
-                                             spec=spec)
-    rows, skmat, one_sided = _sinn.one_sided_operands(qv, rows, state.u,
-                                                      state.l)
+    with jax.named_scope("operands"):
+        qv, rows, qbits = prepare_query_operands(state, q_idx, q_val, budget,
+                                                 spec=spec)
+        rows, skmat, one_sided = _sinn.one_sided_operands(qv, rows, state.u,
+                                                          state.l)
     return qv, rows, qbits, skmat, one_sided
 
 
@@ -149,9 +150,10 @@ def sinnamon_candidate_scores(state, spec, q_idx, q_val, *, budget=None,
 
     Prepares one-sided operands and runs the tile program; the kernel's
     slot axis is padded to a tile multiple (padded slots are gated to -inf
-    and sliced off — works at any post-``grow()`` capacity).  Split from the
-    top-k (:func:`sinnamon_topk_batch` does both) so the staged query tracer
-    can time the sketch scan and the top-k separately.
+    and sliced off — works at any post-``grow()`` capacity).  The operand
+    preparation, the gate and the scan run in the ``operands``, ``topk`` and
+    ``scan`` named scopes, which split a profiled search program's device
+    time.
 
     On a TPU this is always the compiled kernel (``use_kernel`` and
     ``interpret`` default to it); elsewhere the XLA twin
@@ -162,18 +164,21 @@ def sinnamon_candidate_scores(state, spec, q_idx, q_val, *, budget=None,
     use_kernel = on_tpu() if use_kernel is None else use_kernel
     qv, rows, qbits, skmat, one_sided = prepare_fused_operands(
         state, q_idx, q_val, budget, spec=spec)
-    keep = jnp.ones((C,), jnp.bool_) if ok is None else ok
-    gate = jnp.where(keep, 0.0, -jnp.inf).astype(jnp.float32)[None]
-    if not use_kernel:
-        return _sinn.scores_xla(qv, rows, qbits, gate, skmat,
-                                one_sided=one_sided)
-    tile_c = tile_c or _default_tile(C, _sinn.DEFAULT_TILE_C)
-    interpret = _interpret() if interpret is None else interpret
-    s = _sinn.tile_scores(
-        qv, rows, pad_axis(qbits, -1, tile_c // 32),
-        pad_axis(gate, -1, tile_c, fill=-jnp.inf), pad_axis(skmat, 1, tile_c),
-        tile_c=tile_c, one_sided=one_sided, interpret=interpret)
-    return s[:, :C]
+    with jax.named_scope("topk"):
+        keep = jnp.ones((C,), jnp.bool_) if ok is None else ok
+        gate = jnp.where(keep, 0.0, -jnp.inf).astype(jnp.float32)[None]
+    with jax.named_scope("scan"):
+        if not use_kernel:
+            return _sinn.scores_xla(qv, rows, qbits, gate, skmat,
+                                    one_sided=one_sided)
+        tile_c = tile_c or _default_tile(C, _sinn.DEFAULT_TILE_C)
+        interpret = _interpret() if interpret is None else interpret
+        s = _sinn.tile_scores(
+            qv, rows, pad_axis(qbits, -1, tile_c // 32),
+            pad_axis(gate, -1, tile_c, fill=-jnp.inf),
+            pad_axis(skmat, 1, tile_c),
+            tile_c=tile_c, one_sided=one_sided, interpret=interpret)
+        return s[:, :C]
 
 
 def sinnamon_topk_batch(state, spec, q_idx, q_val, kprime, *, budget=None,

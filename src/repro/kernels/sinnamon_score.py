@@ -209,9 +209,11 @@ def sinnamon_score(
 
 def topk_candidates(scores: jax.Array, kp: int) -> tuple:
     """(vals f32[B, kp], slots int32[B, kp]) of gated scores, in
-    ``lax.top_k`` order: score desc, ties by slot asc."""
-    vals, slots = jax.lax.top_k(scores, kp)
-    return vals, slots.astype(jnp.int32)
+    ``lax.top_k`` order: score desc, ties by slot asc (named scope
+    ``topk``)."""
+    with jax.named_scope("topk"):
+        vals, slots = jax.lax.top_k(scores, kp)
+        return vals, slots.astype(jnp.int32)
 
 
 @functools.partial(jax.jit,

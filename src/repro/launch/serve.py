@@ -41,11 +41,9 @@ Observability (see docs/observability.md):
   snapshot), ``/healthz`` (liveness), ``/readyz`` (readiness: 503 until
   the index is built/recovered), plus the ``/debug/*`` surfaces below.
 * ``--event-log FILE`` appends one JSON line per query / maintenance op
-  (trace spans attached on sampled queries); ``--event-log-max-bytes B``
-  rotates the file at B bytes keeping ``--event-log-keep`` segments.
-* ``--trace-every N`` runs every N-th query batch on the staged path,
-  populating per-stage latency histograms (default 32 when metrics or the
-  event log are on, else off; 0 disables).
+  (each query batch with its trace stages: ``device``, ``launch``,
+  ``fetch``); ``--event-log-max-bytes B`` rotates the file at B bytes
+  keeping ``--event-log-keep`` segments.
 * ``--recorder-capacity N`` sizes the tail-sampled flight recorder ring
   (``/debug/requests``, ``/debug/trace/<id>``, ``/debug/batches``);
   ``--record-sample R`` head-samples fast OK requests at rate R (errors,
@@ -190,10 +188,6 @@ def parse_args(argv=None):
                     metavar="S", help="fast burn-rate window")
     ap.add_argument("--slo-slow-window-s", type=float, default=3600.0,
                     metavar="S", help="slow burn-rate window")
-    ap.add_argument("--trace-every", type=int, default=None, metavar="N",
-                    help="run every N-th query batch on the staged path "
-                         "(per-stage histograms); default 32 when metrics "
-                         "or the event log are enabled, 0 = off")
     ap.add_argument("--profile-dir", default=None, metavar="DIR",
                     help="capture a jax.profiler trace of the query loop "
                          "(the launcher fails if the trace cannot start)")
@@ -245,9 +239,6 @@ def parse_args(argv=None):
                                       "with 504 when a fused dispatch is "
                                       "stuck longer than S seconds")
     args = ap.parse_args(argv)
-    if args.trace_every is None:
-        args.trace_every = 32 if (args.metrics_port is not None
-                                  or args.event_log) else 0
     if args.wal is None and (args.snapshot_dir is not None
                              or args.snapshot_every is not None
                              or args.compact_threshold is not None):
@@ -440,8 +431,7 @@ def main():
 
     server = QueryServer(index, k=args.k, kprime=args.kprime,
                          budget=args.budget,
-                         score_backend=args.score_backend,
-                         trace_every=args.trace_every)
+                         score_backend=args.score_backend)
     ready.mark("engine", True)      # built/recovered: ready to serve
     if slo_monitor is not None:
         slo_monitor.start()

@@ -1,6 +1,7 @@
-"""Dependency-free observability: metrics registry, span tracer, request
-trace contexts, tail-sampled flight recorder, SLO monitor, JSONL event
-log, and a stdlib HTTP exposition/debug server.
+"""Dependency-free observability: metrics registry, request trace contexts
+(whose stages are also spans of the JAX profiler's trace), tail-sampled
+flight recorder, SLO monitor, JSONL event log, and a stdlib HTTP
+exposition/debug server.
 
 Everything in this package is importable without JAX so the hot paths can
 instrument themselves unconditionally; the cost of a disabled registry
@@ -36,7 +37,7 @@ from repro.obs.recorder import (
 )
 from repro.obs.server import MetricsServer, ReadyState
 from repro.obs.slo import SLOMonitor, SLOSpec
-from repro.obs.trace import Span, Trace, TraceContext, new_trace_id
+from repro.obs.trace import TraceContext, new_trace_id
 
 __all__ = [
     "Buckets",
@@ -52,8 +53,6 @@ __all__ = [
     "ReadyState",
     "SLOMonitor",
     "SLOSpec",
-    "Span",
-    "Trace",
     "TraceContext",
     "emit",
     "get_event_log",

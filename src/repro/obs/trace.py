@@ -1,33 +1,56 @@
-"""Span tracer and propagated per-request trace context.
+"""Propagated per-request trace context, timed on the profiler's clock too.
 
-Two levels of tracing live here:
+`TraceContext` is the *propagated* per-request context: created at the
+front door (`ServingFrontend.submit`) or at `QueryServer.query*`, threaded
+through quota check → admission queue → batch assembly → device dispatch →
+response, accumulating per-stage wall-clock timings and annotations (which
+coalesced batch the request rode in, its outcome).  Finished contexts go to
+the flight recorder (`repro.obs.recorder`) so a ``QueryResult.trace_id``
+resolves to a full stage breakdown at ``/debug/trace/<id>``.
 
-* `Trace` — a flat list of named spans recorded with a context manager;
-  the serving layer opens one per sampled query and calls
-  `jax.block_until_ready` inside each span so device work is attributed to
-  the stage that launched it (see `QueryServer._search_staged`).
-* `TraceContext` — the *propagated* per-request context (ISSUE 8): created
-  at the front door (`ServingFrontend.submit`) or at `QueryServer.query*`,
-  threaded through quota check → admission queue → batch assembly → device
-  dispatch → response, accumulating per-stage wall-clock timestamps and
-  annotations (which coalesced batch the request rode in, its outcome).
-  Finished contexts go to the flight recorder (`repro.obs.recorder`) so a
-  ``QueryResult.trace_id`` resolves to a full stage breakdown at
-  ``/debug/trace/<id>``.
+Every stage timed with :meth:`TraceContext.stage` is also written into the
+JAX profiler's trace, while a profiler session runs, as a
+``jax.profiler.TraceAnnotation`` named ``PROFILER_NAMES[stage]`` — so the
+host side of a dispatch (``server/device``, ``engine/launch``,
+``engine/fetch``, ...) lies on the same clock as the device operations it
+launched.  With no session running a stage costs two ``perf_counter``
+calls, one ``is_enabled`` check and a list append.  JAX is looked up only
+once a process has imported it: this module stays importable without it.
+
+Code below the server records into the batch's context without taking it
+as a parameter: ``QueryServer.query_many`` activates the context
+(:meth:`TraceContext.activate`) on its thread, and the index's search calls
+the module-level :func:`stage`, which times into whatever context is active
+(or, with none, only annotates the profiler's trace).
 """
 
 from __future__ import annotations
 
 import itertools
 import os
+import sys
 import threading
 import time
 from typing import Optional
 
-__all__ = ["Span", "Trace", "TraceContext", "new_trace_id"]
+__all__ = ["PROFILER_NAMES", "TraceContext", "active", "new_trace_id",
+           "stage"]
+
+#: Context stage -> name of its span in the profiler's trace.  Stages not
+#: listed keep their own name there.
+PROFILER_NAMES = {
+    "assembly": "frontend/assembly",
+    "device": "server/device",
+    "launch": "engine/launch",
+    "fetch": "engine/fetch",
+    "promote": "engine/promote",
+    "respond": "frontend/respond",
+}
 
 _trace_counter = itertools.count(1)
 _trace_lock = threading.Lock()
+_local = threading.local()          # .ctx: the thread's active context
+_annotation = None                  # TraceAnnotation class once resolved
 
 
 def new_trace_id() -> str:
@@ -37,87 +60,84 @@ def new_trace_id() -> str:
     return f"q-{os.getpid():x}-{n:x}"
 
 
-class Span:
-    __slots__ = ("name", "ms")
-
-    def __init__(self, name: str, ms: float):
-        self.name = name
-        self.ms = ms
-
-    def __repr__(self) -> str:
-        return f"Span({self.name!r}, {self.ms:.3f}ms)"
-
-
-class _SpanCtx:
-    __slots__ = ("_trace", "_name", "_t0")
-
-    def __init__(self, trace: "Trace", name: str):
-        self._trace = trace
-        self._name = name
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, exc_type, exc, tb):
-        self._trace.spans.append(
-            Span(self._name, (time.perf_counter() - self._t0) * 1e3)
-        )
-        return False
-
-
-class Trace:
-    """Named collection of timed spans for one operation."""
-
-    __slots__ = ("name", "spans")
-
-    def __init__(self, name: str = "query"):
-        self.name = name
-        self.spans: list[Span] = []
-
-    def span(self, name: str) -> _SpanCtx:
-        """Context manager timing one stage; appends a `Span` on exit."""
-        return _SpanCtx(self, name)
-
-    def total_ms(self) -> float:
-        return sum(s.ms for s in self.spans)
-
-    def stage_ms(self) -> dict:
-        return {s.name: s.ms for s in self.spans}
-
-    def as_dict(self) -> dict:
-        return {
-            "name": self.name,
-            "spans": [{"stage": s.name, "ms": round(s.ms, 4)} for s in self.spans],
-        }
+def _profiler_span(name: str):
+    """An entered ``TraceAnnotation`` for stage ``name`` while a profiler
+    session runs, else None.  A process that has not imported JAX runs no
+    session, so JAX is never imported here."""
+    global _annotation
+    ann = _annotation
+    if ann is None:
+        profiler = sys.modules.get("jax.profiler")
+        if profiler is None:
+            return None
+        ann = _annotation = profiler.TraceAnnotation
+    if not ann.is_enabled():
+        return None
+    span = ann(PROFILER_NAMES.get(name, name))
+    span.__enter__()
+    return span
 
 
 class _CtxSpan:
-    __slots__ = ("_ctx", "_name", "_t0")
+    __slots__ = ("_ctx", "_name", "_t0", "_span")
 
-    def __init__(self, ctx: "TraceContext", name: str):
+    def __init__(self, ctx: Optional["TraceContext"], name: str):
         self._ctx = ctx
         self._name = name
 
     def __enter__(self):
+        self._span = _profiler_span(self._name)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        self._ctx.add_stage(
-            self._name, (time.perf_counter() - self._t0) * 1e3,
-            start_ms=(self._t0 - self._ctx._t0) * 1e3)
+        t1 = time.perf_counter()
+        if self._span is not None:
+            self._span.__exit__(exc_type, exc, tb)
+        ctx = self._ctx
+        if ctx is not None:
+            ctx.stages.append((self._name, (self._t0 - ctx._t0) * 1e3,
+                               (t1 - self._t0) * 1e3))
         return False
+
+
+class _Activation:
+    __slots__ = ("_ctx", "_prev")
+
+    def __init__(self, ctx: "TraceContext"):
+        self._ctx = ctx
+
+    def __enter__(self):
+        self._prev = getattr(_local, "ctx", None)
+        _local.ctx = self._ctx
+        return self._ctx
+
+    def __exit__(self, exc_type, exc, tb):
+        _local.ctx = self._prev
+        return False
+
+
+def active() -> Optional["TraceContext"]:
+    """The context activated on this thread, if any."""
+    return getattr(_local, "ctx", None)
+
+
+def stage(name: str) -> _CtxSpan:
+    """Context manager timing one stage into this thread's active context
+    (see :meth:`TraceContext.activate`); with no active context it only
+    annotates the profiler's trace."""
+    return _CtxSpan(getattr(_local, "ctx", None), name)
 
 
 class TraceContext:
     """One request's propagated trace: id, stage timings, annotations.
 
     Stages are ``(name, start_ms, dur_ms)`` with ``start_ms`` relative to
-    context creation (``None`` for sub-spans imported from a staged
-    `Trace`, which only carry durations).  A context is built up by exactly
-    one thread at a time (submit thread, then the dispatcher) — the
-    hand-off happens through the admission queue, so no locking is needed.
+    context creation (``None`` where only a duration was recorded, such as
+    the batch stages every rider of a coalesced dispatch inherits).  A
+    context is built up by exactly one thread at a time (submit thread,
+    then the dispatcher) — the hand-off happens through the admission
+    queue, so no locking is needed.
 
     The context is deliberately cheap to create and finish (a couple of
     ``perf_counter`` calls and list appends): every request gets one, and
@@ -142,8 +162,15 @@ class TraceContext:
 
     # -- recording -----------------------------------------------------------
     def stage(self, name: str) -> _CtxSpan:
-        """Context manager timing one stage of this request."""
+        """Context manager timing one stage of this request; while a
+        profiler session runs it is also a span of the profiler's trace
+        (``PROFILER_NAMES``)."""
         return _CtxSpan(self, name)
+
+    def activate(self) -> _Activation:
+        """Context manager making this the thread's active context, which
+        the module-level :func:`stage` records into."""
+        return _Activation(self)
 
     def add_stage(self, name: str, dur_ms: float,
                   start_ms: Optional[float] = None) -> None:
@@ -157,11 +184,6 @@ class TraceContext:
     def annotate(self, **fields) -> None:
         """Attach key/value annotations (batch id, width bucket, ...)."""
         self.annotations.update(fields)
-
-    def add_trace(self, trace: Trace, prefix: str = "") -> None:
-        """Import a staged `Trace`'s spans as sub-stages (duration only)."""
-        for s in trace.spans:
-            self.stages.append((prefix + s.name, None, s.ms))
 
     def finish(self, outcome: str, total_ms: Optional[float] = None,
                error: Optional[str] = None) -> "TraceContext":

@@ -204,6 +204,18 @@ def _pad_batch(items, width: int, rows: int):
     return qi, qv
 
 
+#: Batch stages every rider of a coalesced dispatch waited through whole;
+#: the dispatch's own sub-stages (``launch``, ``fetch``, ...) stay on the
+#: batch record, which a request joins through its ``batch_id``.
+_RIDER_STAGES = ("assembly", "device")
+
+
+def _inherit_batch_stages(ctx: TraceContext, bctx: TraceContext) -> None:
+    for name, _start, dur in bctx.stages:
+        if name in _RIDER_STAGES:
+            ctx.add_stage(name, dur)
+
+
 class ServingFrontend:
     """Deadline-aware dynamically batching front end over a `QueryServer`.
 
@@ -613,9 +625,8 @@ class ServingFrontend:
             level = self.degrade.level
             t0 = self._clock()
             try:
-                qi, qv = _pad_batch(live, width, self.max_batch)
-                bctx.add_stage("assembly", (self._clock() - t0) * 1e3,
-                               start_ms=0.0)
+                with bctx.stage("assembly"):
+                    qi, qv = _pad_batch(live, width, self.max_batch)
                 inflight = (self._clock(), live)
                 with self._inflight_lock:
                     self._inflight = inflight
@@ -644,30 +655,27 @@ class ServingFrontend:
             if level > 0:
                 self._m_degraded_queries(level).inc(len(live))
                 bctx.annotate(degrade_level=level)
-            for i, p in enumerate(live):
-                if p.future.done():
-                    continue        # watchdog already 504'd this rider
-                out = res.row(i, k=p.k, trace_id=p.ctx.trace_id)
-                self._m_outcome(p.tenant, "ok").inc()
-                lat_ms = (done - p.enqueued) * 1e3
-                # batch-level stages (assembly + synced device dispatch +
-                # sampled device/* sub-spans) are wall time every rider
-                # waited through, so each request inherits them whole.
-                for name, _start, dur in bctx.stages:
-                    p.ctx.add_stage(name, dur)
-                p.ctx.add_stage("respond", (self._clock() - done) * 1e3)
-                p.ctx.annotate(batch_id=bctx.trace_id, batch_size=len(live),
-                               width_bucket=width,
-                               padding_fraction=round(pad_frac, 4))
-                if level > 0:
-                    p.ctx.annotate(degraded=True, degrade_level=level)
-                retained = self._seal(p.ctx, "ok", lat_ms)
-                self._m_latency(p.tenant).observe(
-                    lat_ms, exemplar=p.ctx.trace_id if retained else None)
-                try:
-                    p.future.set_result(out)
-                except InvalidStateError:
-                    pass            # lost the race to the watchdog
+            with bctx.stage("respond"):
+                for i, p in enumerate(live):
+                    if p.future.done():
+                        continue    # watchdog already 504'd this rider
+                    out = res.row(i, k=p.k, trace_id=p.ctx.trace_id)
+                    self._m_outcome(p.tenant, "ok").inc()
+                    lat_ms = (done - p.enqueued) * 1e3
+                    _inherit_batch_stages(p.ctx, bctx)
+                    p.ctx.add_stage("respond", (self._clock() - done) * 1e3)
+                    p.ctx.annotate(batch_id=bctx.trace_id,
+                                   batch_size=len(live), width_bucket=width,
+                                   padding_fraction=round(pad_frac, 4))
+                    if level > 0:
+                        p.ctx.annotate(degraded=True, degrade_level=level)
+                    retained = self._seal(p.ctx, "ok", lat_ms)
+                    self._m_latency(p.tenant).observe(
+                        lat_ms, exemplar=p.ctx.trace_id if retained else None)
+                    try:
+                        p.future.set_result(out)
+                    except InvalidStateError:
+                        pass        # lost the race to the watchdog
             bctx.finish("ok", total_ms=(self._clock() - t0) * 1e3)
             self._record_batch(bctx, live, width)
             self._live_batch = None
@@ -717,8 +725,7 @@ class ServingFrontend:
                     exc = se
             else:
                 exc = e
-            for name, _start, dur in bctx.stages:
-                p.ctx.add_stage(name, dur)
+            _inherit_batch_stages(p.ctx, bctx)
             lat_ms = (self._clock() - p.enqueued) * 1e3
             if out is not None:
                 recovered += 1

@@ -5,7 +5,7 @@ The two-level serving API (documented in docs/serving.md):
 * **Level 1 — functional**: ``repro.core.engine.search`` /
   ``search_batch`` are pure jittable functions of ``(state, spec)``;
   they return device arrays and exist for composition (shard_map bodies,
-  staged tracing, custom pipelines).
+  custom pipelines).
 * **Level 2 — host serving**: ``QueryServer.query`` / ``query_many`` (and
   the async front door, ``repro.serving.frontend``) own host concerns —
   metrics, tracing, padding — and return a :class:`QueryResult`.

@@ -6,10 +6,13 @@ index with the paper's anytime budget as the latency lever.  ``query`` /
 (ids, scores, k, backend, trace id) — the level-2 host surface over the
 level-1 functional ``engine.search`` / ``search_batch`` (see
 docs/serving.md).  Every query reports into a metrics registry
-(`repro.obs`): latency/batch histograms per scoring backend, plus — on
-sampled queries (``trace_every``) — a per-stage span breakdown
-(admission → sketch scan → top-k merge → rerank) recorded by running the
-same math as separate synced dispatches.  Concurrent-client admission,
+(`repro.obs`): latency/batch histograms per scoring backend.  The batch's
+trace context is active around the device call, so the index records its
+``launch`` and ``fetch`` stages into it; with a profiler session running
+those stages, and the ``device`` stage around them, are spans of the
+profiler's trace, and the fused program's ``operands`` / ``scan`` /
+``topk`` / ``rerank`` named scopes split its device time (see
+docs/observability.md).  Concurrent-client admission,
 dynamic batching and quotas live one level up, in
 ``repro.serving.frontend``; under overload the front door asks for
 degraded answers (``query_many(..., degrade=N)``: shrunken rerank budget,
@@ -19,61 +22,17 @@ then sketch-only scoring — see docs/robustness.md).
 from __future__ import annotations
 
 import time
-from functools import partial
 from typing import Optional, Union
 
-import jax
-import jax.numpy as jnp
-import numpy as np
-
-from repro.core import engine as eng
 from repro.core.engine import SinnamonIndex
 from repro.fault import failpoints as _fp
 from repro.obs import events as obs_events
 from repro.obs import metrics as obs_metrics
 from repro.obs import recorder as obs_recorder
 from repro.obs.instrument import install_engine_gauges
-from repro.obs.trace import Trace, TraceContext
+from repro.obs.trace import TraceContext
 from repro.serving.results import QueryResult
 from repro.serving.sharded import ShardedSinnamonIndex
-
-#: Stage names of the staged (traced) single-device query path, in order.
-QUERY_STAGES = ("admission", "sketch_scan", "topk_merge", "rerank")
-
-#: Stage names of the staged path over a tiered index: the candidate/rerank
-#: split is real (two dispatches with a host slot sync between them), and
-#: the device/prefetch stage — chunk-cache promotion of the candidates'
-#: cold chunks — gets its own span.
-TIERED_QUERY_STAGES = ("admission", "sketch_scan", "prefetch", "rerank")
-
-
-# -- staged query pieces ------------------------------------------------------
-# The production path is ONE fused jit program (engine.search_batch); these
-# are the same stages as separate jitted dispatches, synced between spans so
-# a sampled query can attribute wall time per stage (the SINDI-style
-# breakdown).  Results are bit-identical to the fused path: identical
-# operand prep, identical kernels, identical rerank.
-
-@partial(jax.jit, static_argnums=(1, 4, 5))
-def _gated_scores(state, spec, q_idx, q_val, budget, backend):
-    if backend == "pallas":
-        from repro.kernels import ops as _ops
-        return _ops.sinnamon_candidate_scores(state, spec, q_idx, q_val,
-                                              budget=budget, ok=state.active)
-    s = eng.score_batch(state, spec, q_idx, q_val, budget,
-                        grouped=(backend == "grouped"))
-    return jnp.where(state.active[None, :], s, -jnp.inf)
-
-
-@partial(jax.jit, static_argnums=(1,))
-def _dense_topk(scores, kprime):
-    from repro.kernels import sinnamon_score as _sinn
-    return _sinn.topk_candidates(scores, kprime)
-
-
-@partial(jax.jit, static_argnums=(1,))
-def _rerank(state, k, cand_scores, cand_slots, q_idx, q_val):
-    return eng.rerank_topk(state, cand_scores, cand_slots, q_idx, q_val, k)
 
 
 class QueryServer:
@@ -86,10 +45,8 @@ class QueryServer:
 
     Telemetry: every query records into ``registry`` (default: the
     process-global `repro.obs.metrics.get_registry()`; inject
-    ``NULL_REGISTRY`` to turn metrics off).  With ``trace_every=N > 0``
-    every N-th ``query_many`` batch runs the staged path and publishes
-    per-stage histograms (``repro_query_stage_ms``) plus a ``query`` event
-    with spans attached to the active event log.  Engine health gauges for
+    ``NULL_REGISTRY`` to turn metrics off); each batch also emits a
+    ``query`` event to the active event log.  Engine health gauges for
     ``index`` are installed on construction (weakref — dropping the server
     and index detaches them).
 
@@ -103,7 +60,7 @@ class QueryServer:
                  k: int = 10, kprime: int = 1000,
                  budget: Optional[int] = None, score_fn=None,
                  score_backend: Optional[str] = None,
-                 registry=None, event_log=None, trace_every: int = 0,
+                 registry=None, event_log=None,
                  index_name: str = "index", recorder=None):
         self.index = index
         self.k, self.kprime, self.budget = k, kprime, budget
@@ -113,11 +70,8 @@ class QueryServer:
                          else registry)
         self.event_log = event_log
         self.recorder = recorder
-        self.trace_every = int(trace_every)
         self.stats = {"queries": 0}
         self.last_latency_ms = 0.0       # most recent per-query latency
-        self.last_trace: Optional[Trace] = None
-        self._since_trace = 0
         self._handles: dict = {}
         install_engine_gauges(index, self.registry, name=index_name)
 
@@ -215,21 +169,13 @@ class QueryServer:
         owns = ctx is None
         if owns:
             ctx = TraceContext()
-        trace = None
-        if self.trace_every > 0 and self.score_fn is None and degrade == 0:
-            self._since_trace += 1
-            if self._since_trace >= self.trace_every:
-                self._since_trace = 0
-                trace = Trace()
         sketch_only = (degrade >= 2 and self.score_fn is None
                        and hasattr(self.index, "search_many_sketch"))
         try:
-            with ctx.stage("device"):
+            with ctx.stage("device"), ctx.activate():
                 t0 = time.perf_counter()
                 _fp.fire("device.dispatch")
-                if trace is not None:
-                    ids, scores = self._search_staged(q_idx, q_val, trace)
-                elif sketch_only:
+                if sketch_only:
                     ids, scores = self.index.search_many_sketch(
                         q_idx, q_val, k=self.k, budget=self.budget,
                         backend=self.score_backend)
@@ -253,13 +199,12 @@ class QueryServer:
         if degrade > 0:
             ctx.annotate(degraded=True, degrade_level=int(degrade),
                          sketch_only=sketch_only)
-        self._record(bn, dt_ms, backend, trace, ctx=ctx, owns=owns)
+        self._record(bn, dt_ms, backend, ctx=ctx, owns=owns)
         return QueryResult(ids=ids, scores=scores, k=ids.shape[-1],
                            backend=backend, trace_id=ctx.trace_id,
                            degraded=degrade > 0)
 
     def _record(self, bn: int, dt_ms: float, backend: str,
-                trace: Optional[Trace] = None,
                 ctx: Optional[TraceContext] = None,
                 owns: bool = False) -> None:
         per_query = dt_ms / bn
@@ -268,8 +213,6 @@ class QueryServer:
         retained = None
         if ctx is not None:
             ctx.annotate(backend=backend, batch=bn)
-            if trace is not None:
-                ctx.add_trace(trace, prefix="device/")
             if owns:
                 ctx.finish("ok", total_ms=dt_ms)
                 rec = self._recorder()
@@ -283,105 +226,13 @@ class QueryServer:
                    buckets=obs_metrics.DEFAULT_COUNT_BUCKETS).observe(bn)
         self.registry.counter("repro_queries_total", "Queries served.",
                               labels={"backend": backend}).inc(bn)
-        if trace is not None:
-            self.last_trace = trace
-            self.registry.counter("repro_query_traces_total",
-                                  "Sampled queries run on the staged "
-                                  "(per-stage timed) path.").inc()
-            for span in trace.spans:
-                self._hist("repro_query_stage_ms",
-                           "Wall time per query-path stage (sampled "
-                           "staged dispatches, device-synced per span).",
-                           labels={"stage": span.name,
-                                   "backend": backend}).observe(span.ms)
         log = self.event_log if self.event_log is not None \
             else obs_events.get_event_log()
         if log is not None:
             log.emit("query", batch=bn, ms=round(dt_ms, 4), backend=backend,
                      trace_id=ctx.trace_id if ctx is not None else None,
-                     spans=trace.as_dict()["spans"] if trace else None)
-
-    # -- staged (traced) path ------------------------------------------------
-    def _search_staged(self, q_idx, q_val, trace: Trace):
-        if isinstance(self.index, eng.TieredSinnamonIndex):
-            return self._staged_tiered(q_idx, q_val, trace)
-        if isinstance(self.index, SinnamonIndex):
-            return self._staged_single(q_idx, q_val, trace)
-        return self._staged_generic(q_idx, q_val, trace)
-
-    def _staged_single(self, q_idx, q_val, trace: Trace):
-        index = self.index
-        with trace.span("admission"):
-            spec = index.spec
-            state = index.state
-            backend = self._backend_label()
-            kprime = self.kprime if self.kprime is not None \
-                else max(5 * self.k, self.k)
-            kprime = min(kprime, spec.capacity)
-            k = min(self.k, kprime)
-            q_idx = jnp.asarray(q_idx)
-            q_val = jnp.asarray(q_val)
-        with trace.span("sketch_scan"):
-            scores = _gated_scores(state, spec, q_idx, q_val, self.budget,
-                                   backend)
-            jax.block_until_ready(scores)
-        with trace.span("topk_merge"):
-            cand_scores, cand_slots = _dense_topk(scores, kprime)
-            jax.block_until_ready(cand_scores)
-        with trace.span("rerank"):
-            ids, top_scores, _ = _rerank(state, k, cand_scores, cand_slots,
-                                         q_idx, q_val)
-            out_ids = eng.unpack_ids64(np.asarray(ids))
-            out_scores = np.asarray(top_scores)
-        return out_ids, out_scores
-
-    def _staged_tiered(self, q_idx, q_val, trace: Trace):
-        """Tiered single-device index (see TIERED_QUERY_STAGES): reuses the
-        index's own jitted candidate/rerank programs, so staged results are
-        bit-identical to ``index.search_many``."""
-        index = self.index
-        with trace.span("admission"):
-            spec = index.spec
-            state = index.state
-            kprime = self.kprime if self.kprime is not None \
-                else max(5 * self.k, self.k)
-            kprime = min(kprime, spec.capacity)
-            k = min(self.k, kprime)
-            q_idx = jnp.asarray(q_idx)
-            q_val = jnp.asarray(q_val)
-        with trace.span("sketch_scan"):
-            ub, slots = index._cand(state, spec, q_idx, q_val, kprime,
-                                    self.budget, None, score_fn=None,
-                                    backend=index._backend(self.score_backend))
-            slots_np = np.asarray(slots)             # host sync
-        with trace.span("prefetch"):
-            ridx, rval = index.tiered.gather_rows(slots_np.reshape(-1))
-            jax.block_until_ready((ridx, rval))
-        with trace.span("rerank"):
-            ids, scores, _ = index._rerank_rows(state, ub, slots, ridx, rval,
-                                                q_idx, q_val, k)
-            out_ids = eng.unpack_ids64(np.asarray(ids))
-            out_scores = np.asarray(scores)
-        return out_ids, out_scores
-
-    def _staged_generic(self, q_idx, q_val, trace: Trace):
-        """Sharded (or unknown) index: shard-local stages live inside one
-        shard_map program, so the finest honest split is admission vs the
-        SPMD search dispatch."""
-        with trace.span("admission"):
-            q_idx = np.asarray(q_idx)
-            q_val = np.asarray(q_val)
-        if isinstance(self.index, ShardedSinnamonIndex):
-            # the index records the (synced) spmd_search span itself
-            ids, scores = self.index.search_many(
-                q_idx, q_val, k=self.k, kprime=self.kprime,
-                budget=self.budget, backend=self.score_backend, trace=trace)
-        else:
-            with trace.span("spmd_search"):
-                ids, scores = self.index.search_many(
-                    q_idx, q_val, k=self.k, kprime=self.kprime,
-                    budget=self.budget, backend=self.score_backend)
-        return ids, scores
+                     stages=ctx.to_dict()["stages"] if ctx is not None
+                     else None)
 
     # -- stats ---------------------------------------------------------------
     def latency_percentiles(self):
@@ -393,12 +244,7 @@ class QueryServer:
         return {f"p{p}": h.percentile(p) for p in (50, 90, 99)}
 
     def reset_stats(self) -> None:
-        """Zero the query counter and this server's latency/stage samples
-        (shared-registry histograms for the current backend label)."""
-        backend = self._backend_label()
+        """Zero the query counter and this server's latency samples
+        (the shared-registry histogram for the current backend label)."""
         self.stats["queries"] = 0
-        self.last_trace = None
-        self._latency_hist(backend).reset()
-        for stage in QUERY_STAGES + TIERED_QUERY_STAGES + ("spmd_search",):
-            self._hist("repro_query_stage_ms", "",
-                       labels={"stage": stage, "backend": backend}).reset()
+        self._latency_hist(self._backend_label()).reset()

@@ -33,6 +33,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core import engine as eng
 from repro.distributed import mesh as meshlib
 from repro.distributed import topk
+from repro.obs import trace as obs_trace
 from repro.storage import vecstore
 
 
@@ -104,7 +105,9 @@ def make_search_step(mesh: Mesh, local_spec: eng.EngineSpec, *,
     grouped | pallas — the fused kernel runs per shard; only candidate
     tuples cross shards through the existing hierarchical merge).  The exact
     rerank gathers only the k' candidate CSR rows per shard — no [B, n]
-    dense query block on any path.
+    dense query block on any path.  The shard-local stages carry the
+    engine's named scopes (``operands``, ``scan``, ``topk``), and the rerank
+    with the cross-shard merge runs in ``rerank``.
     """
     from repro.kernels import ops as _ops
 
@@ -125,11 +128,13 @@ def make_search_step(mesh: Mesh, local_spec: eng.EngineSpec, *,
             ub, slots = eng.topk_candidates(state, local_spec, q_idx, q_val,
                                             kl, budget,
                                             backend=backend)  # [b, kl]
-        exact = jax.vmap(
-            lambda s, i, v: vecstore.exact_scores_sparse(state.store, s, i, v)
-        )(slots, q_idx, q_val)                               # [b, kl]
-        exact = jnp.where(jnp.isneginf(ub), -jnp.inf, exact)
-        return _merge_local_exact(mesh, corpus, state, exact, slots, k)
+        with jax.named_scope("rerank"):
+            exact = jax.vmap(
+                lambda s, i, v: vecstore.exact_scores_sparse(state.store, s,
+                                                             i, v)
+            )(slots, q_idx, q_val)                           # [b, kl]
+            exact = jnp.where(jnp.isneginf(ub), -jnp.inf, exact)
+            return _merge_local_exact(mesh, corpus, state, exact, slots, k)
 
     sharded = jax.shard_map(
         local_search, mesh=mesh,
@@ -297,11 +302,12 @@ def make_rerank_rows_step(mesh: Mesh, local_spec: eng.EngineSpec, *, k: int):
     sspec = state_pspecs(mesh, local_spec.upper_only)
 
     def local_rerank(state, ub, slots, ridx, rval, q_idx, q_val):
-        ub, slots = ub[0], slots[0]                      # [b, kl]
-        exact = jax.vmap(vecstore.exact_scores_rows)(ridx[0], rval[0],
-                                                     q_idx, q_val)
-        exact = jnp.where(jnp.isneginf(ub), -jnp.inf, exact)
-        return _merge_local_exact(mesh, corpus, state, exact, slots, k)
+        with jax.named_scope("rerank"):
+            ub, slots = ub[0], slots[0]                  # [b, kl]
+            exact = jax.vmap(vecstore.exact_scores_rows)(ridx[0], rval[0],
+                                                         q_idx, q_val)
+            exact = jnp.where(jnp.isneginf(ub), -jnp.inf, exact)
+            return _merge_local_exact(mesh, corpus, state, exact, slots, k)
 
     sharded = jax.shard_map(
         local_rerank, mesh=mesh,
@@ -532,16 +538,16 @@ class ShardedSinnamonIndex:
                     kprime: Optional[int] = None,
                     budget: Optional[int] = None, score_fn=None,
                     backend: Optional[str] = None,
-                    return_locators: bool = False, trace=None):
+                    return_locators: bool = False):
         """Batched search over [B, Lq] queries (one SPMD dispatch).
 
         ``kprime`` is the per-shard candidate count k'.  ``backend`` picks
         the shard-local scoring backend (None -> process default).  With
         ``return_locators`` the packed (shard, slot) payload of every hit is
-        also returned (decode with topk.unpack_shard_slot).  ``trace`` is an
-        optional `repro.obs.Trace`: the SPMD dispatch (synced) is recorded
-        as one ``spmd_search`` span — shard-local stages run inside a single
-        shard_map program and cannot honestly be split further.
+        also returned (decode with topk.unpack_shard_slot).  The dispatch
+        records ``launch`` and ``fetch`` stages into the thread's active
+        trace context (`repro.obs.trace.stage`); the shard-local split lives
+        in the program's named scopes.
         """
         from repro.kernels import ops as _ops
 
@@ -555,18 +561,14 @@ class ShardedSinnamonIndex:
         step = self._step(key, lambda: make_search_step(
             self.mesh, self.spec, k=k, kprime_local=kl, budget=budget,
             score_fn=score_fn, backend=backend))
-        if trace is not None:
-            with trace.span("spmd_search"):
-                scores, ids, loc = step(self.state, jnp.asarray(q_idx),
-                                        jnp.asarray(q_val))
-                jax.block_until_ready(scores)
-        else:
+        with obs_trace.stage("launch"):
             scores, ids, loc = step(self.state, jnp.asarray(q_idx),
                                     jnp.asarray(q_val))
-        ids = eng.unpack_ids64(np.asarray(ids))
-        if return_locators:
-            return ids, np.asarray(scores), np.asarray(loc)
-        return ids, np.asarray(scores)
+        with obs_trace.stage("fetch"):
+            ids = eng.unpack_ids64(np.asarray(ids))
+            if return_locators:
+                return ids, np.asarray(scores), np.asarray(loc)
+            return ids, np.asarray(scores)
 
     # -- capacity management ------------------------------------------------
     def grow(self, new_local_capacity: Optional[int] = None) -> None:
@@ -696,10 +698,11 @@ class TieredShardedSinnamonIndex(ShardedSinnamonIndex):
                     kprime: Optional[int] = None,
                     budget: Optional[int] = None, score_fn=None,
                     backend: Optional[str] = None,
-                    return_locators: bool = False, trace=None):
+                    return_locators: bool = False):
         """Two SPMD dispatches with a candidate-driven per-shard prefetch in
-        between; with ``trace`` the stages are recorded as separate
-        ``spmd_candidates`` / ``prefetch`` / ``spmd_rerank`` spans."""
+        between, recorded as ``launch`` / ``fetch`` / ``promote`` /
+        ``launch`` / ``fetch`` stages of the thread's active trace
+        context."""
         from repro.kernels import ops as _ops
 
         if score_fn is not None:
@@ -718,27 +721,21 @@ class TieredShardedSinnamonIndex(ShardedSinnamonIndex):
         rstep = self._step(("tiered_rerank", k, kl),
                            lambda: make_rerank_rows_step(self.mesh, self.spec,
                                                          k=k))
-        qi, qv = jnp.asarray(q_idx), jnp.asarray(q_val)
-        if trace is None:
+        with obs_trace.stage("launch"):
+            qi, qv = jnp.asarray(q_idx), jnp.asarray(q_val)
             ub, slots = cstep(self.state, qi, qv)
-            ridx, rval = self._gather_global(np.asarray(slots))
+        with obs_trace.stage("fetch"):
+            slots_np = np.asarray(slots)
+        with obs_trace.stage("promote"):
+            ridx, rval = self._gather_global(slots_np)
+        with obs_trace.stage("launch"):
             scores, ids, loc = rstep(self.state, ub, slots, ridx, rval,
                                      qi, qv)
-        else:
-            with trace.span("spmd_candidates"):
-                ub, slots = cstep(self.state, qi, qv)
-                slots_np = np.asarray(slots)             # sync
-            with trace.span("prefetch"):
-                ridx, rval = self._gather_global(slots_np)
-                jax.block_until_ready((ridx, rval))
-            with trace.span("spmd_rerank"):
-                scores, ids, loc = rstep(self.state, ub, slots, ridx, rval,
-                                         qi, qv)
-                jax.block_until_ready(scores)
-        ids = eng.unpack_ids64(np.asarray(ids))
-        if return_locators:
-            return ids, np.asarray(scores), np.asarray(loc)
-        return ids, np.asarray(scores)
+        with obs_trace.stage("fetch"):
+            ids = eng.unpack_ids64(np.asarray(ids))
+            if return_locators:
+                return ids, np.asarray(scores), np.asarray(loc)
+            return ids, np.asarray(scores)
 
     def _gather_global(self, slots_np: np.ndarray):
         """Per-shard chunk-cache gathers assembled into global [S, B, kl, P]

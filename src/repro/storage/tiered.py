@@ -329,9 +329,7 @@ class TieredVecStore:
     def prefetch(self, slots) -> int:
         """Promote the chunks covering candidate ``slots`` (best effort).
 
-        Returns the number of chunks promoted.  Used by the staged serving
-        path so the ``prefetch`` trace stage accounts the host->device copy
-        separately from the rerank itself.
+        Returns the number of chunks promoted.
         """
         with self._lock:
             chunks = self._chunks_of(slots)

@@ -27,8 +27,9 @@ import urllib.request
 import pytest
 
 from repro.obs import (Buckets, EventLog, FlightRecorder, MetricsRegistry,
-                       MetricsServer, ReadyState, Trace, TraceContext,
+                       MetricsServer, ReadyState, TraceContext,
                        merge_snapshots, parse_exposition, read_events)
+from repro.obs import trace as obs_trace
 from repro.obs.server import build_endpoints, dispatch
 from repro.obs.slo import SLOMonitor, SLOSpec
 
@@ -54,18 +55,21 @@ def test_trace_context_stages_and_seal():
         pass
     ctx.add_stage("work", 2.0)            # repeated names accumulate
     ctx.annotate(batch_id="b-1", width_bucket=32)
-    tr = Trace("staged")
-    with tr.span("scan"):
+    with ctx.activate():
+        with obs_trace.stage("launch"):      # records into the active ctx
+            pass
+    with obs_trace.stage("fetch"):           # no active ctx: not recorded
         pass
-    ctx.add_trace(tr, prefix="device/")
     ctx.finish("ok", total_ms=7.25)
     d = ctx.to_dict()
     assert d["outcome"] == "ok" and d["total_ms"] == 7.25
     assert d["batch_id"] == "b-1" and d["width_bucket"] == 32
     names = [s["stage"] for s in d["stages"]]
-    assert names == ["quota", "work", "work", "device/scan"]
+    assert names == ["quota", "work", "work", "launch"]
     assert d["stages"][0]["start_ms"] == 0.0
-    assert "start_ms" not in d["stages"][3]     # imported spans: dur only
+    assert "start_ms" not in d["stages"][2]     # externally timed: dur only
+    assert d["stages"][3]["start_ms"] >= d["stages"][1]["start_ms"]
+    assert obs_trace.active() is None
     assert ctx.stage_ms()["work"] >= 2.0
     # finish() without total_ms uses the context's own wall clock
     ctx2 = TraceContext().finish("error", error="boom")

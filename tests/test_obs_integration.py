@@ -1,33 +1,42 @@
-"""ISSUE 6 tentpole contracts: telemetry threaded through the live system.
+"""Telemetry threaded through the live system.
 
-* Sampled staged tracing decomposes a served query into the
-  admission -> sketch_scan -> topk_merge -> rerank stages whose spans sum to
-  (almost all of) the measured batch time — and returns results identical
-  to the fused path, for every scoring backend.
+* A trace-context stage is also a span of the JAX profiler's trace, under
+  its mapped name (``server/device``, ``engine/launch``, ...).
+* Under ``QueryServer.query_many`` the batch context gets the index's
+  ``launch`` and ``fetch`` stages, inside its ``device`` stage, for the
+  single-device, sharded and tiered indexes.
+* The fused search program carries the ``operands``, ``scan``, ``topk``
+  and ``rerank`` named scopes in its op metadata, on every backend.
 * A churn-then-query stream over a durable index populates the WAL,
   snapshot, drift and recovery surfaces of one injected registry.
 * The /metrics endpoint serves a parseable Prometheus exposition of all of
-  the above; the event log captures traced queries as JSONL.
-* The sharded index traces as admission -> spmd_search.
+  the above; the event log captures every query batch with its stages.
 * BackgroundCompactor outcomes land in ``repro_compactor_outcomes_total``.
 """
 
+import glob
 import json
+import re
 import time
 import urllib.request
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.engine import EngineSpec, SinnamonIndex
+from repro.core import engine as eng
+from repro.core.engine import EngineSpec, SinnamonIndex, TieredSinnamonIndex
 from repro.data import synth
 from repro.distributed import mesh as meshlib
 from repro.obs import EventLog, MetricsRegistry, MetricsServer
 from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 from repro.obs.metrics import parse_exposition
+from repro.obs.trace import TraceContext
 from repro.persist import compact as compactlib
 from repro.persist.durable import DurableSinnamonIndex
-from repro.serving.serve import QUERY_STAGES, QueryServer
+from repro.serving.serve import QueryServer
 from repro.serving.sharded import ShardedSinnamonIndex
 
 DS = synth.SparseDatasetSpec("t", n=400, psi_doc=20, psi_query=10,
@@ -65,76 +74,121 @@ def index(corpus):
 
 
 # ---------------------------------------------------------------------------
-# staged tracing on the single-device query path
+# trace-context stages on the profiler's clock
 # ---------------------------------------------------------------------------
 
-def test_traced_query_spans_cover_measured_time(corpus, index):
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation`` with a session on."""
+    opened: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    @staticmethod
+    def is_enabled():
+        return True
+
+    def __enter__(self):
+        _FakeAnnotation.opened.append(self.name)
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_stage_annotates_the_profiler_under_its_mapped_name(monkeypatch):
+    monkeypatch.setattr(obs_trace, "_annotation", _FakeAnnotation)
+    _FakeAnnotation.opened = []
+    ctx = TraceContext()
+    with ctx.stage("device"), ctx.activate():
+        with obs_trace.stage("launch"):
+            pass
+        with obs_trace.stage("fetch"):
+            pass
+    with ctx.stage("work"):                 # unmapped: keeps its own name
+        pass
+    assert _FakeAnnotation.opened == ["server/device", "engine/launch",
+                                      "engine/fetch", "work"]
+    assert [name for name, _, _ in ctx.stages] == ["launch", "fetch",
+                                                   "device", "work"]
+
+
+def test_stage_is_a_span_of_a_cpu_profiler_trace(tmp_path):
+    from jax.profiler import ProfileData
+
+    ctx = TraceContext()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with ctx.stage("device"), ctx.activate():
+            with obs_trace.stage("fetch"):
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    spans = {ev.name: (ev.start_ns, ev.duration_ns)
+             for plane in ProfileData.from_file(path).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for ev in line.events
+             if ev.name in ("server/device", "engine/fetch")}
+    assert set(spans) == {"server/device", "engine/fetch"}
+    (ds, dd), (fs, fd) = spans["server/device"], spans["engine/fetch"]
+    assert ds <= fs and fs + fd <= ds + dd and fd >= 2e6
+    assert ctx.stage_ms()["fetch"] >= 2.0
+
+
+def _indexes(kind, corpus):
+    idx, val, _, _ = corpus
+    if kind == "sharded":
+        mesh = meshlib.single_device_mesh(("data", "model"))
+        index = ShardedSinnamonIndex(_spec(), mesh)
+    elif kind == "tiered":
+        index = TieredSinnamonIndex(_spec(), tier_chunk_slots=16,
+                                    cache_chunks=2)
+    else:
+        index = SinnamonIndex(_spec())
+    _churn(index, idx, val)
+    return index
+
+
+@pytest.mark.parametrize("kind", ["single", "sharded", "tiered"])
+def test_query_many_records_launch_and_fetch_inside_device(corpus, kind):
     _, _, qi, qv = corpus
-    reg = MetricsRegistry()
-    srv = QueryServer(index, k=5, kprime=32, registry=reg, trace_every=1)
-    srv.query_many(qi, qv)                 # staged-path compile warmup
-    t0 = time.perf_counter()
-    srv.query_many(qi, qv)
-    dt_ms = (time.perf_counter() - t0) * 1e3
-    trace = srv.last_trace
-    assert trace is not None
-    assert tuple(s.name for s in trace.spans) == QUERY_STAGES
-    # spans are nested inside the measured window, and the device syncs
-    # between spans mean they account for nearly all of it
-    assert trace.total_ms() <= dt_ms * 1.02
-    assert trace.total_ms() >= 0.5 * dt_ms
-    for stage in QUERY_STAGES:
-        h = reg.histogram("repro_query_stage_ms",
-                          labels={"stage": stage,
-                                  "backend": srv._backend_label()})
-        assert h.count == 2, stage
-    assert reg.counter("repro_query_traces_total").value == 2
+    srv = QueryServer(_indexes(kind, corpus), k=5, kprime=32,
+                      registry=MetricsRegistry())
+    ctx = TraceContext(tenant="batch")
+    res = srv.query_many(qi, qv, ctx=ctx)
+    assert res.ids.shape == (len(qi), 5)
+    (device,) = [st for st in ctx.stages if st[0] == "device"]
+    inner = [st for st in ctx.stages if st[0] != "device"]
+    want = ["launch", "fetch"] + (["promote", "launch", "fetch"]
+                                  if kind == "tiered" else [])
+    assert [name for name, _, _ in inner] == want
+    lo, hi = device[1], device[1] + device[2]
+    for _name, start, dur in inner:
+        assert lo <= start and start + dur <= hi + 1e-6
+    assert obs_trace.active() is None
 
 
-def test_traced_path_matches_fused_results_per_backend(corpus, index):
-    _, _, qi, qv = corpus
-    for backend in ("reference", "grouped", "pallas"):
-        reg = MetricsRegistry()
-        srv = QueryServer(index, k=5, kprime=32, registry=reg,
-                          trace_every=1, score_backend=backend)
-        ids_t, sc_t = srv.query_many(qi, qv)
-        assert srv.last_trace is not None, backend
-        ids_f, sc_f = index.search_many(qi, qv, k=5, kprime=32,
-                                        backend=backend)
-        np.testing.assert_array_equal(ids_t, ids_f)
-        np.testing.assert_allclose(sc_t, sc_f, rtol=1e-6)
-        h = reg.histogram("repro_query_stage_ms",
-                          labels={"stage": "sketch_scan", "backend": backend})
-        assert h.count == 1, backend
+_SCOPES = ("operands", "scan", "topk", "rerank")
 
 
-def test_untraced_batches_skip_staging(corpus, index):
-    _, _, qi, qv = corpus
-    reg = MetricsRegistry()
-    srv = QueryServer(index, k=5, kprime=32, registry=reg, trace_every=3)
-    for _ in range(6):
-        srv.query_many(qi, qv)
-    assert reg.counter("repro_query_traces_total").value == 2   # 2 of 6
-    assert srv.stats["queries"] == 48
-    b = srv._backend_label()
-    assert reg.histogram("repro_query_latency_ms",
-                         labels={"backend": b}).count == 48
-    assert reg.counter("repro_queries_total", labels={"backend": b}).value \
-        == 48
-
-
-def test_sharded_trace_stages(corpus):
-    idx, val, qi, qv = corpus
-    mesh = meshlib.single_device_mesh(("data", "model"))
-    sharded = ShardedSinnamonIndex(_spec(), mesh)
-    _churn(sharded, idx, val)
-    reg = MetricsRegistry()
-    srv = QueryServer(sharded, k=5, kprime=32, registry=reg, trace_every=1)
-    ids_t, sc_t = srv.query_many(qi, qv)
-    assert tuple(s.name for s in srv.last_trace.spans) \
-        == ("admission", "spmd_search")
-    ids_f, sc_f = sharded.search_many(qi, qv, k=5, kprime=32)
-    np.testing.assert_array_equal(ids_t, ids_f)
+@pytest.mark.parametrize("backend", ["reference", "grouped", "pallas"])
+def test_search_program_carries_named_scopes(backend):
+    spec = _spec()
+    search = jax.jit(eng.search_batch, static_argnums=(1, 4, 5, 6),
+                     static_argnames=("score_fn", "backend"))
+    qi = jnp.zeros((4, 16), jnp.int32)
+    qv = jnp.ones((4, 16), jnp.float32)
+    text = search.lower(jax.eval_shape(lambda: eng.init(spec)), spec, qi,
+                        qv, 5, 32, None, None,
+                        backend=backend).as_text(debug_info=True)
+    names = set(re.findall(r'loc\("(jit\(search_batch\)/[^"]*)"', text))
+    for scope in _SCOPES:
+        # inside a vmap the scope reads ``vmap(<scope>)``
+        rx = re.compile(rf"(^|[/(]){scope}([/)]|$)")
+        assert any(rx.search(n) for n in names), (scope, sorted(names)[:20])
+    rerank = [n for n in names if re.search(r"(^|[/(])rerank([/)]|$)", n)]
+    assert any("searchsorted" in n for n in rerank)
 
 
 # ---------------------------------------------------------------------------
@@ -161,23 +215,25 @@ def test_event_log_captures_traced_queries(tmp_path, corpus, index):
     path = str(tmp_path / "events.jsonl")
     with EventLog(path) as log:
         srv = QueryServer(index, k=5, kprime=32, registry=MetricsRegistry(),
-                          event_log=log, trace_every=2)
+                          event_log=log)
         for _ in range(4):
             srv.query_many(qi, qv)
     with open(path) as f:
         events = [json.loads(line) for line in f]
     queries = [e for e in events if e["event"] == "query"]
     assert len(queries) == 4
-    traced = [e for e in queries if e.get("spans")]
-    assert len(traced) == 2
-    assert [s["stage"] for s in traced[0]["spans"]] == list(QUERY_STAGES)
+    for e in queries:
+        assert [s["stage"] for s in e["stages"]] == ["launch", "fetch",
+                                                     "device"]
+        ms = {s["stage"]: s["ms"] for s in e["stages"]}
+        assert ms["launch"] + ms["fetch"] <= ms["device"] + 1e-3
     assert all("ts" in e and e["level"] == "INFO" for e in queries)
 
 
 def test_metrics_http_endpoint_serves_parseable_exposition(corpus, index):
     _, _, qi, qv = corpus
     reg = MetricsRegistry()
-    srv = QueryServer(index, k=5, kprime=32, registry=reg, trace_every=1)
+    srv = QueryServer(index, k=5, kprime=32, registry=reg)
     srv.query_many(qi, qv)
     with MetricsServer(registry=reg, port=0) as ms:
         with urllib.request.urlopen(ms.url + "/metrics", timeout=10) as r:
@@ -190,9 +246,8 @@ def test_metrics_http_endpoint_serves_parseable_exposition(corpus, index):
             assert r.read() == b"ok\n"
     flat = parse_exposition(text)          # raises on malformed lines
     names = {name for name, _ in flat}
-    for required in ("repro_query_latency_ms_count",
-                     "repro_query_stage_ms_count", "repro_engine_live_docs",
-                     "repro_engine_bytes"):
+    for required in ("repro_query_latency_ms_count", "repro_queries_total",
+                     "repro_engine_live_docs", "repro_engine_bytes"):
         assert required in names, required
     assert doc["repro_query_latency_ms"]["type"] == "histogram"
 

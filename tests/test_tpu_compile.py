@@ -86,3 +86,35 @@ def test_dense_kernel_compiles_for_v5e(one_chip, no_compile_cache):
                                              interpret=False)
 
     assert "tpu_custom_call" in _compiled_text(step, *shapes)
+
+
+def test_search_program_keeps_its_named_scopes_on_v5e(one_chip,
+                                                      no_compile_cache,
+                                                      monkeypatch):
+    """The named scopes survive the TPU compiler into the op metadata a
+    profile reads: the kernel under ``scan``, the rerank's loop under
+    ``rerank``, the operand gathers under ``operands``."""
+    import re
+
+    from repro.core import engine as eng
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    spec = eng.EngineSpec(n=4096, m=16, h=1, capacity=1 << 14, max_nnz=128,
+                          positive_only=True, dtype="float32",
+                          value_dtype="bfloat16")
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: eng.init(spec)))
+    q = [jax.ShapeDtypeStruct((B, 32), dt, sharding=one_chip)
+         for dt in (jnp.int32, jnp.float32)]
+    search = jax.jit(eng.search_batch, static_argnums=(1, 4, 5, 6),
+                     static_argnames=("score_fn", "backend"))
+    text = search.lower(state, spec, *q, 10, 128, None, None,
+                        backend="pallas").compile().as_text()
+    kernel, = [ln for ln in text.splitlines()
+               if re.match(r"\s*%tile_scores[.\d]* = ", ln)]
+    assert "jit(search_batch)/scan/" in kernel
+    loops = [ln for ln in text.splitlines() if " while(" in ln]
+    assert loops and all("jit(search_batch)/rerank/" in ln for ln in loops)
+    assert 'op_name="jit(search_batch)/operands/' in text
