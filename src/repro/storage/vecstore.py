@@ -7,9 +7,11 @@ Algorithm 7.  On TPU the natural representation is **padded CSR** over slots:
     indices : int32[C, P]   active coordinates, padded with -1
     values  : f32/bf16[C, P]
 
-Fetching k' candidates is a row gather; exact inner products are a gather of
-``q_dense[indices]`` plus a masked dot — dense, regular, MXU/VPU-friendly.
-The same primitive scanned over *all* slots is the TPU-native exact LinScan
+Fetching k' candidates is a row gather; the exact rerank then matches each
+row's coordinates against the sparse query by comparison
+(:func:`exact_scores_rows`).  Against a dense query, exact inner products
+are a gather of ``q_dense[indices]`` plus a masked dot; that primitive
+scanned over *all* slots is the TPU-native exact LinScan
 ("document-ordered scan"; see DESIGN.md §2).
 """
 
@@ -65,28 +67,6 @@ def densify_query(n: int, q_idx: Array, q_val: Array) -> Array:
     return jnp.zeros((n,), jnp.float32).at[safe].add(contrib, mode="drop")
 
 
-def combine_query(q_idx: Array, q_val: Array) -> tuple:
-    """Sort query coordinates (pads routed last) and combine duplicates.
-
-    Returns ``(qs, comb)``: sorted coordinate keys (pad = int32 max) and, at
-    every position, the TOTAL value of its coordinate's duplicate run — the
-    same sum densify_query's scatter-add produces.  The combine is a sorted
-    segment-sum: O(ψ_q log ψ_q) for the sort plus one length-ψ_q scatter-add,
-    replacing the old O(ψ_q²) pairwise-equality mask.
-    """
-    big = jnp.int32(jnp.iinfo(jnp.int32).max)
-    key = jnp.where(q_idx >= 0, q_idx, big)
-    order = jnp.argsort(key)
-    qs = key[order]                                  # sorted coords, pads last
-    qv = jnp.where(q_idx >= 0, q_val.astype(jnp.float32), 0.0)[order]
-    if qs.shape[0] == 0:         # static shape: nothing to combine
-        return qs, qv
-    start = jnp.concatenate([jnp.ones((1,), jnp.bool_), qs[1:] != qs[:-1]])
-    seg = jnp.cumsum(start) - 1                      # [L] run id per position
-    sums = jnp.zeros_like(qv).at[seg].add(qv)        # segment totals
-    return qs, sums[seg]                             # broadcast back to runs
-
-
 def exact_scores_rows(idx: Array, val: Array, q_idx: Array,
                       q_val: Array) -> Array:
     """Exact ⟨q, x⟩ for pre-gathered CSR rows (idx int32[K, P], val [K, P]).
@@ -94,13 +74,22 @@ def exact_scores_rows(idx: Array, val: Array, q_idx: Array,
     The row-level Algorithm 7 rerank primitive: both the resident path
     (:func:`exact_scores_sparse`) and the tiered path
     (``TieredVecStore.gather_rows`` → rerank) delegate here, so tiering is
-    bit-identical to the resident baseline by construction.  f32[K].
+    bit-identical to the resident baseline by construction.
+
+    Each stored coordinate finds its query value by comparison with all ψ_q
+    query coordinates, summed over the query axis: O(K·P·ψ_q) compare-selects
+    on the vector unit and no gather (on a TPU an element gather costs far
+    more than ψ_q compare-selects at every query pad in use).  XLA fuses the
+    compare, select and sum into one reduction, so no ``[K, P, ψ_q]`` block
+    is written.  Duplicate query coordinates add up inside the sum (the
+    total densify_query's scatter-add gives); a negative coordinate on
+    either side is a pad, and the query's pads carry no value, so no pad
+    adds anything.  f32[K].
     """
     val = val.astype(jnp.float32)
-    qs, comb = combine_query(q_idx, q_val)
-    pos = jnp.clip(jnp.searchsorted(qs, idx), 0, qs.shape[0] - 1)
-    hit = (jnp.take(qs, pos) == idx) & (idx >= 0)
-    qd = jnp.where(hit, jnp.take(comb, pos), 0.0)    # [K, P]
+    q_val = jnp.where(q_idx >= 0, q_val.astype(jnp.float32), 0.0)
+    hit = idx[..., None] == q_idx                    # [K, P, ψ_q], fused
+    qd = jnp.sum(jnp.where(hit, q_val, 0.0), axis=-1)    # [K, P]
     return jnp.sum(qd * val, axis=-1)
 
 
@@ -109,11 +98,12 @@ def exact_scores_sparse(store: VecStore, slots: Array, q_idx: Array,
     """Exact ⟨q, x_s⟩ for the given slots WITHOUT densifying the query.
 
     The Algorithm 7 rerank used by every scoring backend: gathers only the
-    k' candidate CSR rows and matches their coordinates against the sorted
-    sparse query via searchsorted — O(k'·P·log ψ_q) and no R^n scatter, so a
+    k' candidate CSR rows and matches their coordinates against the sparse
+    query by comparison (:func:`exact_scores_rows`) — O(k'·P·ψ_q)
+    compare-selects, no gather per coordinate and no R^n scatter, so a
     batched rerank never allocates a ``[B, n]`` dense query block.
-    Duplicate query coordinates are pre-combined by addition (the same
-    result densify_query's scatter-add produces).  f32[len(slots)].
+    Duplicate query coordinates add up (the same result densify_query's
+    scatter-add produces).  f32[len(slots)].
     """
     return exact_scores_rows(store.indices[slots], store.values[slots],
                              q_idx, q_val)

@@ -188,7 +188,10 @@ def test_search_program_carries_named_scopes(backend):
         rx = re.compile(rf"(^|[/(]){scope}([/)]|$)")
         assert any(rx.search(n) for n in names), (scope, sorted(names)[:20])
     rerank = [n for n in names if re.search(r"(^|[/(])rerank([/)]|$)", n)]
-    assert any("searchsorted" in n for n in rerank)
+    assert rerank
+    # the rerank matches coordinates by comparison: no binary-search loop
+    assert not any("searchsorted" in n or "while" in n for n in rerank), \
+        sorted(rerank)
 
 
 # ---------------------------------------------------------------------------

@@ -1,10 +1,11 @@
-"""The scoring kernel compiles for a TPU v5e at the serving shapes.
+"""The scoring kernel and the exact rerank compile for a TPU v5e at the
+serving shapes.
 
 Compiles against a described (not attached) v5e chip: the TPU compiler
 rejects what interpret mode accepts (unaligned blocks, primitives without a
 Mosaic lowering), so these tests guard the chip path without a chip.  Shapes
 are the chip smoke's: a batch of 16 queries of 64 coordinates over 2^20
-slots, m=64 sketch rows per side.
+slots, m=64 sketch rows per side; the rerank's are the benchmark cells'.
 """
 
 import os
@@ -15,6 +16,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import sinnamon_score
+from repro.storage import vecstore
 
 B, L, M, C = 16, 64, 64, 1 << 20
 KPRIME = 800
@@ -92,8 +94,9 @@ def test_search_program_keeps_its_named_scopes_on_v5e(one_chip,
                                                       no_compile_cache,
                                                       monkeypatch):
     """The named scopes survive the TPU compiler into the op metadata a
-    profile reads: the kernel under ``scan``, the rerank's loop under
-    ``rerank``, the operand gathers under ``operands``."""
+    profile reads: the kernel under ``scan``, the rerank's comparison under
+    ``rerank``, the operand gathers under ``operands``; the program runs no
+    loop."""
     import re
 
     from repro.core import engine as eng
@@ -115,6 +118,25 @@ def test_search_program_keeps_its_named_scopes_on_v5e(one_chip,
     kernel, = [ln for ln in text.splitlines()
                if re.match(r"\s*%tile_scores[.\d]* = ", ln)]
     assert "jit(search_batch)/scan/" in kernel
-    loops = [ln for ln in text.splitlines() if " while(" in ln]
-    assert loops and all("jit(search_batch)/rerank/" in ln for ln in loops)
+    assert " while(" not in text
+    assert 'op_name="jit(search_batch)/rerank/' in text
     assert 'op_name="jit(search_batch)/operands/' in text
+
+
+@pytest.mark.parametrize("B,K,P,W", [(16, 800, 176, 96), (16, 400, 160, 160)],
+                         ids=["splade", "g100"])
+def test_exact_rerank_compiles_without_loop_or_product_on_v5e(
+        one_chip, no_compile_cache, B, K, P, W):
+    """The batched exact rerank at the two cells' widths (k' rows of P
+    coordinates, a query pad of W) compiles to no loop, and its temporaries
+    stay below twice the gathered rows' bytes: the ``[B, k', P, W]``
+    comparison is fused into its reduction, never written."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(jax.vmap(vecstore.exact_scores_rows)).lower(
+        sds((B, K, P), jnp.int32), sds((B, K, P), jnp.bfloat16),
+        sds((B, W), jnp.int32), sds((B, W), jnp.float32)).compile()
+    assert "while" not in compiled.as_text()
+    rows_bytes = B * K * P * (4 + 2)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * rows_bytes

@@ -3,14 +3,16 @@
 The invariant under test is the rerank oracle equivalence behind the whole
 tier-transparency story: for ANY padded CSR rows and ANY sparse query —
 duplicate query coordinates, pads in arbitrary positions, all-pad rows,
-negative values — the sparse searchsorted rerank
-(:func:`exact_scores_sparse`, which both the resident and the tiered path
-delegate to through :func:`exact_scores_rows`) must equal the dense-scatter
+negative values — the sparse rerank (:func:`exact_scores_sparse`, which
+both the resident and the tiered path delegate to through
+:func:`exact_scores_rows`: a comparison of every stored coordinate with the
+query's coordinates, summed over the query) must equal the dense-scatter
 oracle ``exact_scores(store, slots, densify_query(...))`` EXACTLY.
 
 Values are drawn as multiples of 1/8 so every partial sum is exact in
-float32 — equality failures mean a real combine/matching bug, never
-summation-order ULP noise.
+float32 — equality failures mean a real matching bug, never
+summation-order ULP noise.  ``tests/test_exact_rerank.py`` checks the same
+oracle at the serving widths without this optional dependency.
 """
 
 import numpy as np
@@ -83,25 +85,6 @@ def test_sparse_rerank_equals_dense_oracle(seed, rows, max_nnz, qlen,
     if all_pad_row:
         empty_pos = int(np.where(np.asarray(slots) == 0)[0][0])
         assert float(np.asarray(got)[empty_pos]) == 0.0
-
-
-@given(seed=st.integers(0, 10_000), qlen=st.integers(1, 16))
-@settings(max_examples=40, deadline=None)
-def test_combine_query_matches_dense_totals(seed, qlen):
-    """combine_query's per-coordinate totals are exactly densify_query's
-    scatter-add sums, and pads sort last with zero contribution."""
-    rng = np.random.default_rng(seed)
-    q_idx, q_val = _query(rng, qlen, dup_frac=0.5)
-    qs, comb = vecstore.combine_query(q_idx, q_val)
-    qs, comb = np.asarray(qs), np.asarray(comb)
-    dense = np.asarray(vecstore.densify_query(N, q_idx, q_val))
-
-    big = np.iinfo(np.int32).max
-    assert np.all(np.diff(qs.astype(np.int64)) >= 0), "keys must be sorted"
-    for k, c in zip(qs, comb):
-        if k == big:
-            continue
-        assert c == dense[k], (k, c, dense[k])
 
 
 @given(seed=st.integers(0, 10_000))
